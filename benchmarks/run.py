@@ -25,6 +25,8 @@ import json
 import time
 from pathlib import Path
 
+from repro.launch.compile_cache import enable_compile_cache
+
 ROWS: list[tuple[str, float, str]] = []
 
 
@@ -670,12 +672,13 @@ def bench_kernels(
     )
     jit_oracle_bwd = jax.jit(lambda ct: jax.vjp(gru_scan_ref, xg, whh, bhh)[1](ct))
     jit_resid_bwd = jax.jit(gru_scan_bwd_ref)
-    pallas_bwd = lambda: gru_scan_bwd(xg, whh, bhh, h_seq, dy, interpret=True)
+    jit_pallas_bwd = jax.jit(lambda *a: gru_scan_bwd(*a, interpret=True))
+    pallas_bwd = lambda: jit_pallas_bwd(xg, whh, bhh, h_seq, dy)
 
     gru = {
         "shape": {"batch": gru_batch, "seq": t_len, "hidden": n_hid},
         "fwd_us": {
-            "pallas_interpret": timeit(gru_scan, xg, whh, bhh),
+            "pallas_interpret": timeit(jax.jit(gru_scan), xg, whh, bhh),
             "jnp_ref": timeit(jax.jit(gru_scan_ref), xg, whh, bhh),
         },
         "bwd_us": {
@@ -728,8 +731,10 @@ def bench_kernels(
     )
     jit_oracle_bwd = jax.jit(lambda ct: jax.vjp(ssd_chunk_scan_ref, *ssd_args)[1](ct))
     jit_resid_bwd = jax.jit(ssd_chunk_scan_bwd_ref)
-    pallas_bwd = lambda: ssd_chunk_scan_bwd(*ssd_args, states, dyc, interpret=True)
-    fwd_kernel = lambda: ssd_chunk_scan(*ssd_args)
+    jit_pallas_bwd = jax.jit(lambda *a: ssd_chunk_scan_bwd(*a, interpret=True))
+    pallas_bwd = lambda: jit_pallas_bwd(*ssd_args, states, dyc)
+    jit_fwd_kernel = jax.jit(ssd_chunk_scan)
+    fwd_kernel = lambda: jit_fwd_kernel(*ssd_args)
 
     ssd = {
         "shape": {
@@ -802,6 +807,7 @@ def bench_roofline() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.05)
     ap.add_argument("--seeds", type=int, nargs="+", default=[0, 1])

@@ -31,11 +31,13 @@ from repro.data.pipeline import build_client_datasets
 from repro.data.synth_eicu import CohortConfig, generate_cohort
 from repro.experiments.paper import shared_time_to_target
 from repro.federated.runtime import AsyncFederation, AsyncFederationConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.gru import GRUConfig, init_gru, make_loss_fn
 from repro.optim.adamw import AdamW
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.05, help="cohort scale (1.0 = 89k stays)")
     ap.add_argument("--seed", type=int, default=0)

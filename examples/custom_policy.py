@@ -21,6 +21,7 @@ from repro.federated import (
     RecruitmentPolicy,
     register_recruitment,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.gru import GRUConfig, init_gru, make_loss_fn
 from repro.optim import AdamW
 
@@ -46,6 +47,7 @@ class MedianBandRecruitment(RecruitmentPolicy):
 
 
 def main() -> None:
+    enable_compile_cache()
     cohort = generate_cohort(CohortConfig().scaled(0.02), seed=0)
     clients = build_client_datasets(cohort)
     model_cfg = GRUConfig()

@@ -19,6 +19,7 @@ from repro.configs import get_config
 from repro.core.histogram import token_histogram
 from repro.core.recruitment import BALANCED, ClientStats, recruit
 from repro.federated.fedavg import aggregate
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models.zoo import Model
 from repro.optim.adamw import AdamW
@@ -39,6 +40,7 @@ def make_client_corpus(rng, vocab, skew: float):
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_config("smollm-135m").reduced()
     model = Model(cfg, remat=False)
     optimizer = AdamW(learning_rate=1e-3)

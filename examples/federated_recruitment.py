@@ -103,9 +103,11 @@ import argparse
 import json
 
 from repro.experiments.paper import ExperimentConfig, build_cohort, run_setting
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.3, help="cohort scale (1.0 = 89k stays)")
     ap.add_argument("--seed", type=int, default=0)

@@ -16,12 +16,14 @@ import numpy as np
 
 from repro.data import CohortConfig, build_client_datasets, generate_cohort
 from repro.federated import Federation, FederationConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.gru import GRUConfig, init_gru, make_loss_fn
 from repro.optim import AdamW
 from repro.privacy import DPConfig, ScenarioConfig, apply_scenario
 
 
 def main() -> None:
+    enable_compile_cache()
     cohort = generate_cohort(CohortConfig().scaled(0.02), seed=0)
     clients = build_client_datasets(cohort)[:12]
     model_cfg = GRUConfig(dropout=0.0, hidden_dim=8, num_layers=1)

@@ -9,12 +9,14 @@ import numpy as np
 from repro.core import BALANCED, recruit
 from repro.data import CohortConfig, build_client_datasets, generate_cohort, global_dataset
 from repro.federated import Federation, FederationConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.metrics import evaluate_predictions
 from repro.models.gru import GRUConfig, gru_apply, init_gru, make_loss_fn
 from repro.optim import AdamW
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1. a synthetic multi-hospital ICU cohort (5% of the paper's scale)
     cohort = generate_cohort(CohortConfig().scaled(0.05), seed=0)
     clients = build_client_datasets(cohort)

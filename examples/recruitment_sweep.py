@@ -12,9 +12,11 @@ import dataclasses
 from repro.core import BALANCED, DATA_GREEDY, QUALITY_GREEDY, recruit, recruitment_curve
 from repro.data import CohortConfig, build_client_datasets, generate_cohort
 from repro.experiments.paper import ExperimentConfig, build_cohort, run_setting
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.1)
     ap.add_argument("--train", action="store_true", help="also train at each gamma_th")

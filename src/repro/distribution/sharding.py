@@ -19,7 +19,6 @@ from typing import Any, Sequence
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.distribution.compat import get_active_mesh
 
 AxisLike = Any  # None | str | tuple[str, ...]
 
@@ -29,7 +28,11 @@ POD = "pod"
 
 
 def active_mesh():
-    return get_active_mesh()
+    """The mesh set by ``jax.sharding.set_mesh``, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
+        return None
+    return mesh
 
 
 def clean_spec(spec: Sequence[AxisLike] | P) -> P | None:

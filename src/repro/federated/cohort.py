@@ -300,16 +300,14 @@ class CohortTrainer:
             return weighted_sum_stacked(stacked, weights, axis_name=axis_name), losses
 
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
-
             sharded = functools.partial(
-                shard_map,
+                jax.shard_map,
                 mesh=mesh,
                 in_specs=(
                     P(), P("data"), P("data"), P("data"), P("data"), P("data"), P("data"),
                 ),
                 out_specs=(P(), P("data")),
-                check_rep=False,
+                check_vma=False,
             )
             train_block = sharded(functools.partial(train_block, axis_name="data"))
             train_block_resident = sharded(

@@ -1,21 +1,20 @@
 """Shared backend selection for the Pallas kernel tier.
 
-Both kernel families (``gru_scan``, ``ssd``) need the same three decisions,
-previously copy-pasted as private ``_on_tpu()`` probes:
+Both kernel families (``gru_scan``, ``ssd``) need the same decisions:
 
 * ``on_tpu()``      — is the default JAX backend a real TPU?
 * ``interpret()``   — should ``pallas_call`` run in interpret mode?  True
   off-TPU (CPU containers, CI) so the same kernel source stays executable
-  everywhere; on TPU the Mosaic compiler takes over.
+  everywhere; on TPU the Mosaic compiler always takes over.
 * ``pallas_backward()`` — should the *backward* pass use the hand-written
   Pallas kernel (True on TPU) or the pure-jnp residual reverse scan (the
   off-TPU default, which is faster than interpret-mode emulation on CPU)?
 
-The ``REPRO_PALLAS_INTERPRET`` environment variable overrides both
-``interpret()`` and ``pallas_backward()`` to True, forcing every path —
-including the backward kernels — through interpret-mode ``pallas_call`` on
-any backend.  CI uses this to exercise the backward kernels without TPU
-hardware; it is read at trace time, so set it before the first jit.
+The ``REPRO_PALLAS_INTERPRET`` environment variable acts only off-TPU: it
+routes the backward pass through the interpret-mode Pallas kernels too, so
+CI exercises them without TPU hardware.  On a TPU it changes nothing — a
+kernel there is compiled, never interpreted behind the caller's back.  It
+is read at trace time, so set it before the first jit.
 """
 
 from __future__ import annotations
@@ -38,17 +37,20 @@ def on_tpu() -> bool:
 
 
 def interpret() -> bool:
-    """Interpret-mode flag for ``pallas_call`` (True off-TPU or when forced)."""
-    if _env_forced():
-        return True
+    """Interpret-mode flag for ``pallas_call``: True exactly when off-TPU."""
     return not on_tpu()
+
+
+def resolve_interpret(flag: bool | None) -> bool:
+    """A kernel's ``interpret`` argument: ``None`` means :func:`interpret`."""
+    return interpret() if flag is None else bool(flag)
 
 
 def pallas_backward() -> bool:
     """Route the backward pass through the Pallas backward kernel?
 
-    True on TPU (compiled Mosaic) or when ``REPRO_PALLAS_INTERPRET`` forces
-    interpret-mode coverage; otherwise False and the pure-jnp residual
-    reverse scan runs instead.
+    True on TPU (compiled Mosaic), or off-TPU when ``REPRO_PALLAS_INTERPRET``
+    asks for interpret-mode coverage; otherwise False and the pure-jnp
+    residual reverse scan runs instead.
     """
     return on_tpu() or _env_forced()

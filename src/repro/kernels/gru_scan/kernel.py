@@ -13,15 +13,20 @@ Grid: batch tiles.  Per program instance the VMEM working set is
 ``(B_TILE, T, 3N) + (N, 3N) + (B_TILE, N)`` — for the paper's N=32 this is
 a few hundred KB; B_TILE=128 keeps the per-step ``(B_TILE, N) @ (N, 3N)``
 matmul MXU-shaped on the batch dimension.
+
+The bias travels as a ``(1, 3N)`` row.  The cohort engine vmaps the kernel
+over clients, which adds a leading client axis to every block; a 1-D
+``(3N,)`` block would then sit over a ``(C, 3N)`` array with a partial
+second-to-last dimension, which Mosaic's tiling rule refuses.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve_interpret as _interpret
 
 
 def _gru_kernel(xg_ref, w_hh_ref, b_hh_ref, out_ref):
@@ -29,11 +34,11 @@ def _gru_kernel(xg_ref, w_hh_ref, b_hh_ref, out_ref):
     b_tile, t_len, three_n = xg_ref.shape
     n = three_n // 3
     w_hh = w_hh_ref[...].astype(jnp.float32)        # (N, 3N) resident in VMEM
-    b_hh = b_hh_ref[...].astype(jnp.float32)        # (3N,)
+    b_hh = b_hh_ref[...].astype(jnp.float32)        # (1, 3N)
 
     def step(t, h):
         gx = xg_ref[:, t, :].astype(jnp.float32)    # (B_TILE, 3N)
-        gh = h @ w_hh + b_hh[None, :]
+        gh = h @ w_hh + b_hh
         xr, xz, xn = gx[:, :n], gx[:, n : 2 * n], gx[:, 2 * n :]
         hr, hz, hn = gh[:, :n], gh[:, n : 2 * n], gh[:, 2 * n :]
         r = jax.nn.sigmoid(xr + hr)
@@ -47,16 +52,19 @@ def _gru_kernel(xg_ref, w_hh_ref, b_hh_ref, out_ref):
     jax.lax.fori_loop(0, t_len, step, h0)
 
 
-@functools.partial(jax.jit, static_argnames=("b_tile", "interpret"))
 def gru_scan(
     x_gates: jnp.ndarray,   # (B, T, 3N) = x @ W_ih + b_ih
     w_hh: jnp.ndarray,      # (N, 3N)
     b_hh: jnp.ndarray,      # (3N,)
     *,
     b_tile: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Hidden-state sequence (B, T, N)."""
+    """Hidden-state sequence (B, T, N).
+
+    ``interpret=None`` takes ``backend.interpret()``: compiled on a TPU,
+    interpreted elsewhere.
+    """
     b, t, three_n = x_gates.shape
     n = three_n // 3
     b_tile = min(b_tile, b)
@@ -71,12 +79,12 @@ def gru_scan(
         in_specs=[
             pl.BlockSpec((b_tile, t, three_n), lambda i: (i, 0, 0)),
             pl.BlockSpec((n, three_n), lambda i: (0, 0)),
-            pl.BlockSpec((three_n,), lambda i: (0,)),
+            pl.BlockSpec((1, three_n), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((b_tile, t, n), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_tiles * b_tile, t, n), x_gates.dtype),
-        interpret=interpret,
-    )(x_gates, w_hh, b_hh)
+        interpret=_interpret(interpret),
+    )(x_gates, w_hh, b_hh.reshape(1, three_n))
     return out[:b]
 
 
@@ -107,7 +115,7 @@ def _gru_bwd_kernel(xg_ref, w_hh_ref, b_hh_ref, h_ref, dy_ref, dxg_ref, dw_ref, 
         gx = xg_ref[:, t, :].astype(jnp.float32)                      # (B, 3N)
         h_prev = jnp.where(t > 0, h_ref[:, tm1, :].astype(jnp.float32), 0.0)
         dy_t = dy_ref[:, t, :].astype(jnp.float32)
-        gh = h_prev @ w_hh + b_hh[None, :]
+        gh = h_prev @ w_hh + b_hh
         xr, xz, xn = gx[:, :n], gx[:, n : 2 * n], gx[:, 2 * n :]
         hr, hz, hn = gh[:, :n], gh[:, n : 2 * n], gh[:, 2 * n :]
         r = jax.nn.sigmoid(xr + hr)
@@ -124,19 +132,18 @@ def _gru_bwd_kernel(xg_ref, w_hh_ref, b_hh_ref, h_ref, dy_ref, dxg_ref, dw_ref, 
         dxg_ref[:, t, :] = d_gx.astype(dxg_ref.dtype)
 
         dh_new = dh_total * z + d_gh @ w_hh.T
-        return dh_new, dw + h_prev.T @ d_gh, db + d_gh.sum(axis=0)
+        return dh_new, dw + h_prev.T @ d_gh, db + d_gh.sum(axis=0, keepdims=True)
 
     carry0 = (
         jnp.zeros((b_tile, n), dtype=jnp.float32),
         jnp.zeros((n, three_n), dtype=jnp.float32),
-        jnp.zeros((three_n,), dtype=jnp.float32),
+        jnp.zeros((1, three_n), dtype=jnp.float32),
     )
     _, dw_tile, db_tile = jax.lax.fori_loop(0, t_len, step, carry0)
     dw_ref[...] += dw_tile.astype(dw_ref.dtype)
     db_ref[...] += db_tile.astype(db_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("b_tile", "interpret"))
 def gru_scan_bwd(
     x_gates: jnp.ndarray,   # (B, T, 3N)
     w_hh: jnp.ndarray,      # (N, 3N)
@@ -145,7 +152,7 @@ def gru_scan_bwd(
     dy: jnp.ndarray,        # (B, T, N)  output cotangent
     *,
     b_tile: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Single-pass Pallas backward: ``(dx_gates, dw_hh, db_hh)``."""
     b, t, three_n = x_gates.shape
@@ -165,20 +172,20 @@ def gru_scan_bwd(
         in_specs=[
             pl.BlockSpec((b_tile, t, three_n), lambda i: (i, 0, 0)),
             pl.BlockSpec((n, three_n), lambda i: (0, 0)),
-            pl.BlockSpec((three_n,), lambda i: (0,)),
+            pl.BlockSpec((1, three_n), lambda i: (0, 0)),
             pl.BlockSpec((b_tile, t, n), lambda i: (i, 0, 0)),
             pl.BlockSpec((b_tile, t, n), lambda i: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((b_tile, t, three_n), lambda i: (i, 0, 0)),
             pl.BlockSpec((n, three_n), lambda i: (0, 0)),
-            pl.BlockSpec((three_n,), lambda i: (0,)),
+            pl.BlockSpec((1, three_n), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((num_tiles * b_tile, t, three_n), x_gates.dtype),
             jax.ShapeDtypeStruct((n, three_n), jnp.float32),
-            jax.ShapeDtypeStruct((three_n,), jnp.float32),
+            jax.ShapeDtypeStruct((1, three_n), jnp.float32),
         ],
-        interpret=interpret,
-    )(x_gates, w_hh, b_hh, h_seq, dy)
-    return dxg[:b], dw_hh.astype(w_hh.dtype), db_hh.astype(b_hh.dtype)
+        interpret=_interpret(interpret),
+    )(x_gates, w_hh, b_hh.reshape(1, three_n), h_seq, dy)
+    return dxg[:b], dw_hh.astype(w_hh.dtype), db_hh.reshape(three_n).astype(b_hh.dtype)

@@ -1,8 +1,9 @@
 """Jitted public wrapper: full GRU layer = hoisted MXU matmul + Pallas scan.
 
-Backend selection lives in ``repro.kernels.backend``: interpret mode is
-forced off-TPU, and ``REPRO_PALLAS_INTERPRET=1`` forces every path —
-including the backward kernel — through interpret-mode ``pallas_call``.
+Backend selection lives in ``repro.kernels.backend``: the kernels compile
+on TPU and run in interpret mode elsewhere, and off-TPU
+``REPRO_PALLAS_INTERPRET=1`` also routes the backward pass through the
+interpret-mode backward kernel.
 
 ``pallas_call`` has no reverse-mode rule, so the op carries a
 ``custom_vjp``.  The forward stashes its own output (the hidden-state
@@ -25,20 +26,18 @@ from repro.kernels.gru_scan.ref import gru_scan_bwd_ref, gru_scan_ref
 
 @jax.custom_vjp
 def gru_scan_op(x_gates: jnp.ndarray, w_hh: jnp.ndarray, b_hh: jnp.ndarray) -> jnp.ndarray:
-    return gru_scan(x_gates, w_hh, b_hh, interpret=backend.interpret())
+    return gru_scan(x_gates, w_hh, b_hh)
 
 
 def _fwd(x_gates, w_hh, b_hh):
-    h_seq = gru_scan(x_gates, w_hh, b_hh, interpret=backend.interpret())
+    h_seq = gru_scan(x_gates, w_hh, b_hh)
     return h_seq, (x_gates, w_hh, b_hh, h_seq)
 
 
 def _bwd(residuals, cotangent):
     x_gates, w_hh, b_hh, h_seq = residuals
     if backend.pallas_backward():
-        return gru_scan_bwd(
-            x_gates, w_hh, b_hh, h_seq, cotangent, interpret=backend.interpret()
-        )
+        return gru_scan_bwd(x_gates, w_hh, b_hh, h_seq, cotangent)
     return gru_scan_bwd_ref(x_gates, w_hh, b_hh, h_seq, cotangent)
 
 
@@ -50,7 +49,7 @@ def gru_scan_oracle(x_gates: jnp.ndarray, w_hh: jnp.ndarray, b_hh: jnp.ndarray) 
     """The pre-residual pairing (benchmark baseline only): Pallas forward,
     backward recomputes the whole forward through the jnp oracle and
     transposes it."""
-    return gru_scan(x_gates, w_hh, b_hh, interpret=backend.interpret())
+    return gru_scan(x_gates, w_hh, b_hh)
 
 
 def _oracle_fwd(x_gates, w_hh, b_hh):

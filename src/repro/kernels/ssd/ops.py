@@ -1,10 +1,10 @@
 """Jitted public wrapper for the SSD Pallas kernel.
 
 Accepts the chunked layout produced by ``repro.models.mamba2``; backend
-selection (interpret mode, backward routing, ``REPRO_PALLAS_INTERPRET``)
-lives in ``repro.kernels.backend``.  ``ssd_full`` is the convenience entry
-point taking an unchunked sequence (used by tests to sweep shapes against
-the oracle).
+selection (interpret mode off-TPU, backward routing,
+``REPRO_PALLAS_INTERPRET``) lives in ``repro.kernels.backend``.
+``ssd_full`` is the convenience entry point taking an unchunked sequence
+(used by tests to sweep shapes against the oracle).
 
 ``pallas_call`` has no reverse-mode rule, so the op carries a
 ``custom_vjp``.  The forward stashes the chunk-entry states S_k as the
@@ -25,43 +25,21 @@ from repro.kernels.ssd.kernel import ssd_chunk_scan_bwd as _kernel_bwd
 from repro.kernels.ssd.ref import ssd_chunk_scan_bwd_ref, ssd_chunk_scan_ref
 
 
-def _pick_h_tile(h: int) -> int:
-    for cand in (4, 2, 1):
-        if h % cand == 0:
-            return cand
-    return 1
-
-
 @jax.custom_vjp
 def ssd_chunk_scan(xc, dtc, cum, bc, cc):
     """Chunked inputs (B, NC, L, ...) -> y (B, NC, L, H, P)."""
-    h = xc.shape[3]
-    return _kernel(
-        xc, dtc, cum, bc, cc, h_tile=_pick_h_tile(h), interpret=backend.interpret()
-    )
+    return _kernel(xc, dtc, cum, bc, cc)
 
 
 def _fwd(xc, dtc, cum, bc, cc):
-    h = xc.shape[3]
-    y, states = _kernel(
-        xc,
-        dtc,
-        cum,
-        bc,
-        cc,
-        h_tile=_pick_h_tile(h),
-        interpret=backend.interpret(),
-        return_states=True,
-    )
+    y, states = _kernel(xc, dtc, cum, bc, cc, return_states=True)
     return y, (xc, dtc, cum, bc, cc, states)
 
 
 def _bwd(residuals, cotangent):
     xc, dtc, cum, bc, cc, states = residuals
     if backend.pallas_backward():
-        return _kernel_bwd(
-            xc, dtc, cum, bc, cc, states, cotangent, interpret=backend.interpret()
-        )
+        return _kernel_bwd(xc, dtc, cum, bc, cc, states, cotangent)
     return ssd_chunk_scan_bwd_ref(xc, dtc, cum, bc, cc, states, cotangent)
 
 
@@ -72,10 +50,7 @@ ssd_chunk_scan.defvjp(_fwd, _bwd)
 def ssd_chunk_scan_oracle(xc, dtc, cum, bc, cc):
     """The pre-residual pairing (benchmark baseline only): Pallas forward,
     backward recomputes the whole forward through the jnp oracle."""
-    h = xc.shape[3]
-    return _kernel(
-        xc, dtc, cum, bc, cc, h_tile=_pick_h_tile(h), interpret=backend.interpret()
-    )
+    return _kernel(xc, dtc, cum, bc, cc)
 
 
 def _oracle_fwd(xc, dtc, cum, bc, cc):

@@ -33,7 +33,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P, set_mesh
 
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.hlo_analysis import (
@@ -44,7 +44,6 @@ from repro.launch.hlo_analysis import (
     memory_summary,
     model_flops_estimate,
 )
-from repro.distribution.compat import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (
     INPUT_SHAPES,

@@ -955,4 +955,9 @@ def main(argv: Sequence[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    # The CLI, not main(): tests drive main() in-process and keep the
+    # persistent compilation cache off.
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

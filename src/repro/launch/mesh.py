@@ -14,7 +14,13 @@ FedAvg aggregation across silos is an all-reduce over ``pod``.
 
 from __future__ import annotations
 
-from repro.distribution.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axis types (sharding by annotation)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,8 +39,6 @@ def make_data_mesh(num_devices: int | None = None):
     axis.  On CPU, force multiple devices with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before* jax
     initializes (CI's multi-device matrix leg does exactly this)."""
-    import jax
-
     return make_mesh((num_devices or jax.device_count(),), ("data",))
 
 

@@ -12,8 +12,11 @@ Two optional instruments, both wired through the job spec's strict
   ``jit.round_compiles`` gauge — hot-path recompilation becomes an
   assertable regression rather than a silent slowdown.
 
-Both degrade to no-ops when jax is missing or the monitoring API is
-unavailable, keeping ``repro.obs`` importable without jax.
+Neither swallows a failure: a profiler that cannot start or stop, or a
+listener that cannot register, raises into the run that asked for it, so a
+run never exits 0 with its instrumentation silently missing.  jax is
+imported only when an instrument is used, keeping ``repro.obs`` importable
+without it.
 """
 
 from __future__ import annotations
@@ -81,19 +84,14 @@ class RoundProfiler:
         self.log_dir = str(log_dir)
         self._active = False
         self._seen = 0
-        self._failed = False
 
     def round_start(self, round_index: int) -> None:
-        if self._failed or self.rounds <= 0 or self._active or self._seen >= self.rounds:
+        if self.rounds <= 0 or self._active or self._seen >= self.rounds:
             return
-        try:
-            import jax.profiler
+        import jax.profiler
 
-            jax.profiler.start_trace(self.log_dir)
-            self._active = True
-        except Exception:
-            # Missing profiler backend must never take down a training run.
-            self._failed = True
+        jax.profiler.start_trace(self.log_dir)
+        self._active = True
 
     def round_end(self, round_index: int) -> None:
         if not self._active:
@@ -105,47 +103,37 @@ class RoundProfiler:
     def stop(self) -> None:
         if not self._active:
             return
-        try:
-            import jax.profiler
+        import jax.profiler
 
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
         self._active = False
+        jax.profiler.stop_trace()
 
 
 # One process-global jax.monitoring listener fans out to live watchers;
 # jax exposes register but no public unregister, so the listener is
 # installed once and consults this list.
 _ACTIVE_WATCHERS: list["CompileWatcher"] = []
-_LISTENER_STATE = {"installed": False, "available": True}
+_LISTENER_STATE = {"installed": False}
 
 
-def _install_listener() -> bool:
+def _install_listener() -> None:
     if _LISTENER_STATE["installed"]:
-        return True
-    if not _LISTENER_STATE["available"]:
-        return False
-    try:
-        import jax.monitoring
+        return
+    import jax.monitoring
 
-        def on_event(event: str, **kw: Any) -> None:
-            if "compile" in event:
-                for watcher in _ACTIVE_WATCHERS:
-                    watcher.compiles += 1
+    def on_event(event: str, **kw: Any) -> None:
+        if "compile" in event:
+            for watcher in _ACTIVE_WATCHERS:
+                watcher.compiles += 1
 
-        def on_duration(event: str, duration: float, **kw: Any) -> None:
-            if "compile" in event:
-                for watcher in _ACTIVE_WATCHERS:
-                    watcher.compile_time_s += duration
+    def on_duration(event: str, duration: float, **kw: Any) -> None:
+        if "compile" in event:
+            for watcher in _ACTIVE_WATCHERS:
+                watcher.compile_time_s += duration
 
-        jax.monitoring.register_event_listener(on_event)
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        _LISTENER_STATE["installed"] = True
-        return True
-    except Exception:
-        _LISTENER_STATE["available"] = False
-        return False
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    _LISTENER_STATE["installed"] = True
 
 
 class CompileWatcher:
@@ -163,16 +151,14 @@ class CompileWatcher:
         self.compile_time_s = 0.0
         self._polled_compiles = 0
         self._polled_time_s = 0.0
-        self.available = False
 
     def __enter__(self) -> "CompileWatcher":
-        self.available = _install_listener()
-        if self.available:
-            _ACTIVE_WATCHERS.append(self)
+        _install_listener()
+        _ACTIVE_WATCHERS.append(self)
         return self
 
     def __exit__(self, *exc_info: object) -> None:
-        if self.available and self in _ACTIVE_WATCHERS:
+        if self in _ACTIVE_WATCHERS:
             _ACTIVE_WATCHERS.remove(self)
 
     def poll(self) -> int:

@@ -25,6 +25,12 @@ from repro.models.gru import GRUConfig, init_gru, make_loss_fn
 from repro.optim.adamw import AdamW
 
 SEQ_LEN, FEAT = 4, 6
+# Reusing a donated buffer raises from whichever dispatch path runs the op:
+# jax's Python path raises RuntimeError("Array has been deleted"), while an
+# op whose C++ fast path is already warm in this process (any earlier test
+# in the same worker that ran it) hands the buffer to the runtime, which
+# refuses it with ValueError("... deleted or donated").
+REUSE_ERRORS = (RuntimeError, ValueError)
 
 
 def make_clients(count: int, n: int, rng: np.random.Generator) -> list[ClientDataset]:
@@ -87,9 +93,9 @@ def test_donated_accumulator_is_invalidated(model):
     )
     jax.block_until_ready(out_acc)
     assert all(leaf.is_deleted() for leaf in acc_leaves)
-    with pytest.raises(RuntimeError, match="deleted"):
+    with pytest.raises(REUSE_ERRORS, match="deleted"):
         _ = acc_leaves[0] + 1.0
-    with pytest.raises(RuntimeError, match="deleted"):
+    with pytest.raises(REUSE_ERRORS, match="deleted"):
         np.asarray(acc_leaves[-1])
     # the round's *output* accumulator is alive and well
     assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(out_acc))

@@ -238,6 +238,17 @@ def test_backend_interpret_env_override(monkeypatch):
     assert backend.pallas_backward() == on_tpu
 
 
+def test_env_override_never_interprets_on_tpu(monkeypatch):
+    """On a TPU the kernels compile whatever REPRO_PALLAS_INTERPRET says;
+    an explicit ``interpret`` argument still wins."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert backend.interpret() is False
+    assert backend.resolve_interpret(None) is False
+    assert backend.resolve_interpret(True) is True
+    assert backend.pallas_backward() is True
+
+
 def test_forced_interpret_routes_backward_through_pallas(monkeypatch):
     """With REPRO_PALLAS_INTERPRET=1 the custom_vjp backward runs the
     hand-written Pallas kernels (interpret mode) — and still matches."""

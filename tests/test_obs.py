@@ -19,6 +19,7 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -31,6 +32,7 @@ from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.profile import (
     CompileWatcher,
     ObservabilityConfig,
+    RoundProfiler,
     resolve_observability,
 )
 from repro.obs.report import render_report
@@ -277,6 +279,37 @@ class TestCompileWatcher:
         with CompileWatcher(None) as watcher:
             watcher.compiles += 1
             assert watcher.poll() == 1
+
+    def test_counts_a_real_compile(self):
+        with CompileWatcher(MetricsRegistry()) as watcher:
+            jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+        assert watcher.compiles >= 1
+
+
+class TestRoundProfiler:
+    def test_start_failure_raises(self, monkeypatch, tmp_path):
+        import jax.profiler
+
+        def refuse(log_dir):
+            raise RuntimeError("no profiler backend")
+
+        monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+        profiler = RoundProfiler(1, str(tmp_path))
+        with pytest.raises(RuntimeError, match="no profiler backend"):
+            profiler.round_start(0)
+
+    def test_stop_failure_raises(self, monkeypatch, tmp_path):
+        import jax.profiler
+
+        def refuse():
+            raise RuntimeError("trace lost")
+
+        monkeypatch.setattr(jax.profiler, "start_trace", lambda log_dir: None)
+        monkeypatch.setattr(jax.profiler, "stop_trace", refuse)
+        profiler = RoundProfiler(1, str(tmp_path))
+        profiler.round_start(0)
+        with pytest.raises(RuntimeError, match="trace lost"):
+            profiler.round_end(0)
 
 
 # ---------------------------------------------------------------------------

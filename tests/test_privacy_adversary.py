@@ -58,7 +58,14 @@ def _fixture():
     clients = build_client_datasets(cohort)[:N_CLIENTS]
     mcfg = GRUConfig(dropout=0.0, hidden_dim=8, num_layers=1)
     loss_fn = make_loss_fn(mcfg)
-    params0 = init_gru(jax.random.key(0), mcfg)
+    # The recipe's initial params are part of the recipe.  They were drawn
+    # on jax 0.4's threefry stream; jax 0.5 made jax_threefry_partitionable
+    # the default, which draws different params from the same key, so the
+    # stream is pinned here.  On the partitionable stream key 0 is the one
+    # draw of keys 0-8 where trimmed-mean:0.35 degrades past ROBUST_TOL under
+    # label-flip (clean 0.2595, attacked 0.4090); keys 1-8 all hold.
+    with jax.threefry_partitionable(False):
+        params0 = init_gru(jax.random.key(0), mcfg)
     vx = jnp.asarray(np.concatenate([np.asarray(c.val.x) for c in clients]))
     vy = jnp.asarray(np.concatenate([np.asarray(c.val.y) for c in clients]))
     vm = jnp.ones(vy.shape[0], jnp.float32)

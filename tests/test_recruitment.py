@@ -109,8 +109,7 @@ def test_recruited_are_lowest_nu():
 
 # Shared strategies for the recruitment property tests.  A population is a
 # list of (histogram, sample-size) pairs — sizes drawn independently of the
-# histogram mass so the n^-1/2 term is exercised on its own.  Everything
-# here works under both real hypothesis and tests/_hypothesis_fallback.
+# histogram mass so the n^-1/2 term is exercised on its own.
 HISTOGRAMS = st.lists(st.integers(0, 50), min_size=NUM_BINS, max_size=NUM_BINS).filter(
     lambda c: sum(c) > 0
 )

@@ -7,7 +7,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
-from repro.distribution.compat import set_mesh
+from jax.sharding import set_mesh
 from repro.distribution.sharding import clean_spec, constrain
 from repro.launch.mesh import data_axes, make_host_mesh
 from repro.launch.specs import (
