@@ -618,8 +618,8 @@ def bench_kernels(
                       the jnp oracle, then transposes it
       residual_jnp  — new default off-TPU: single reverse scan over stashed
                       residuals, no forward recompute
-      pallas_bwd    — the hand-written backward kernel (interpret mode here,
-                      Mosaic-compiled on TPU)
+      pallas_bwd    — the hand-written backward kernel, in interpret mode
+                      on every backend (as are the forward kernel timings)
 
     Also embeds the jaxpr recompute-elimination report (scan sites + FLOP
     accounting of the backward-only graph).  Writes ``BENCH_kernels.json``.
@@ -634,6 +634,7 @@ def bench_kernels(
     from repro.kernels.gru_scan.kernel import gru_scan, gru_scan_bwd
     from repro.kernels.gru_scan.ops import gru_scan_op, gru_scan_oracle
     from repro.kernels.gru_scan.ref import gru_scan_bwd_ref, gru_scan_ref
+    from repro.kernels.ssd.kernel import ssd_chunk_scan as ssd_chunk_scan_kernel
     from repro.kernels.ssd.kernel import ssd_chunk_scan_bwd
     from repro.kernels.ssd.ops import ssd_chunk_scan, ssd_chunk_scan_oracle
     from repro.kernels.ssd.ref import (
@@ -664,7 +665,10 @@ def bench_kernels(
     dy = jnp.asarray(rng.normal(size=(gru_batch, t_len, n_hid)), jnp.float32)
     h_seq = gru_scan_ref(xg, whh, bhh)
 
-    err_fwd = float(jnp.max(jnp.abs(gru_scan(xg, whh, bhh) - h_seq)))
+    # "pallas_interpret" entries pin interpret mode, so they mean the same
+    # on every backend (the kernels default to Mosaic on a TPU).
+    jit_pallas_fwd = jax.jit(lambda *a: gru_scan(*a, interpret=True))
+    err_fwd = float(jnp.max(jnp.abs(jit_pallas_fwd(xg, whh, bhh) - h_seq)))
     _, oracle_vjp = jax.vjp(gru_scan_ref, xg, whh, bhh)
     g_oracle = oracle_vjp(dy)
     maxerr = lambda got: max(
@@ -678,7 +682,7 @@ def bench_kernels(
     gru = {
         "shape": {"batch": gru_batch, "seq": t_len, "hidden": n_hid},
         "fwd_us": {
-            "pallas_interpret": timeit(jax.jit(gru_scan), xg, whh, bhh),
+            "pallas_interpret": timeit(jit_pallas_fwd, xg, whh, bhh),
             "jnp_ref": timeit(jax.jit(gru_scan_ref), xg, whh, bhh),
         },
         "bwd_us": {
@@ -723,7 +727,9 @@ def bench_kernels(
 
     y_ref = ssd_chunk_scan_ref(*ssd_args)
     states = ssd_chunk_states_ref(*ssd_args)
-    err_fwd = float(jnp.max(jnp.abs(ssd_chunk_scan(*ssd_args) - y_ref)))
+    jit_fwd_kernel = jax.jit(lambda *a: ssd_chunk_scan_kernel(*a, interpret=True))
+    fwd_kernel = lambda: jit_fwd_kernel(*ssd_args)
+    err_fwd = float(jnp.max(jnp.abs(fwd_kernel() - y_ref)))
     _, oracle_vjp = jax.vjp(ssd_chunk_scan_ref, *ssd_args)
     g_oracle = oracle_vjp(dyc)
     maxerr = lambda got: max(
@@ -733,8 +739,6 @@ def bench_kernels(
     jit_resid_bwd = jax.jit(ssd_chunk_scan_bwd_ref)
     jit_pallas_bwd = jax.jit(lambda *a: ssd_chunk_scan_bwd(*a, interpret=True))
     pallas_bwd = lambda: jit_pallas_bwd(*ssd_args, states, dyc)
-    jit_fwd_kernel = jax.jit(ssd_chunk_scan)
-    fwd_kernel = lambda: jit_fwd_kernel(*ssd_args)
 
     ssd = {
         "shape": {

@@ -21,10 +21,12 @@ Phases:
   staging, donation on.  The loss is finite and round 2 compiles nothing
   (``jit.round_compiles`` in ``metrics.jsonl``).
 - ``federated-src``: nu-greedy recruitment, 2 rounds.
-- ``pallas``: ``federated-ac`` with ``model.use_pallas``, 1 round.  The
-  round module handed to XLA holds the Mosaic kernel (``tpu_custom_call``,
-  so it was compiled, not interpreted) and its loss matches round 1 of
-  ``federated-ac`` within ``PALLAS_LOSS_RTOL``.
+- ``pallas``: ``federated-ac`` with ``model.use_pallas``, the same 2
+  rounds.  The round module handed to XLA holds the Mosaic kernel
+  (``tpu_custom_call``, so it was compiled, not interpreted); round 1's
+  loss matches ``federated-ac`` within ``PALLAS_LOSS_RTOL``, round 2's
+  within ``PALLAS_DRIFT_RTOL`` and the final parameters within
+  ``PALLAS_PARAM_ATOL``.
 - ``reference``: one round over 8 recruited clients with the vectorized
   engine on the chip, against the sequential engine on the host CPU at
   float32 matmul precision.
@@ -62,12 +64,21 @@ COHORT_SCALE = 1.0
 PAPER_COUNTS = {"hospitals": 189, "stays": 89_127, "train_stays": 62_375}
 REFERENCE_CLIENTS = 8
 
-# Pallas vs XLA scan, round-1 mean local loss.  Both compute the same
+# Pallas vs XLA scan over the same 2 rounds.  Both compute the same
 # recurrence from the same batches and keys; only where the recurrent
-# matmul runs and the order of its sums differ, so the losses agree to
-# rounding.  A wrong kernel (gate order, a dropped bias, a stale hidden
-# state) moves a loss of order 1 by far more than 1%.
-PALLAS_LOSS_RTOL = 1e-2
+# matmul runs and the order of its sums differ.  Round 1 starts both from
+# the same params, so its loss isolates the kernel: 6.5e-8 relative in
+# every TPU v5e run so far, and 1e-4 leaves room for summation order only.
+PALLAS_LOSS_RTOL = 1e-4
+# Round 2 starts from params that already differ.  AdamW's early steps are
+# about the learning rate (5e-3) per coordinate whatever the gradient's
+# size, so where a gradient is near zero its sign, and so the step, can
+# differ between two summation orders.  One TPU v5e reading: round-2 loss
+# 6.7e-5 relative, final params 1.32e-3 max abs.  The limits are 15x and
+# 7.6x those; a wrong kernel moves params by the size of a round's update
+# (0.18 in the ``reference`` phase).
+PALLAS_DRIFT_RTOL = 1e-3
+PALLAS_PARAM_ATOL = 1e-2
 # Chip vs host-CPU reference, max |param diff| after one round.  Both at
 # float32 matmul precision: only summation order and the chip's
 # transcendental functions differ, and AdamW's normalised steps can carry
@@ -210,7 +221,7 @@ def phase_federated_ac(state: dict) -> None:
     )
     recompiles = run["metrics"][1]["gauges"].get("jit.round_compiles")
     check(recompiles == 0, f"round 2 compiled {recompiles} programs, expected 0")
-    state["ac_round1_loss"] = run["records"][0]["mean_local_loss"]
+    state["federated-ac"] = run
 
 
 def phase_federated_src() -> None:
@@ -230,7 +241,7 @@ def phase_pallas(state: dict) -> None:
     before = jax.config.values["jax_dump_ir_to"]
     jax.config.update("jax_dump_ir_to", ir_dir)
     try:
-        run = submit("pallas", job_spec("federated-ac", rounds=1, use_pallas=True))
+        run = submit("pallas", job_spec("federated-ac", use_pallas=True))
     finally:
         jax.config.update("jax_dump_ir_to", before)
     modules = sorted(glob.glob(os.path.join(ir_dir, "*cohort_round*_compile.mlir")))
@@ -242,14 +253,19 @@ def phase_pallas(state: dict) -> None:
     shutil.rmtree(ir_dir, ignore_errors=True)
     log(f"  round modules={len(modules)} with tpu_custom_call={with_kernel}")
     check(bool(with_kernel), "no round module holds tpu_custom_call: the kernel did not compile")
-    loss = run["records"][0]["mean_local_loss"]
-    ref = state["ac_round1_loss"]
-    rel = abs(loss - ref) / abs(ref)
-    log(
-        f"  round-1 loss pallas={loss!r} xla_scan={ref!r} rel_diff={rel!r} "
-        f"rtol={PALLAS_LOSS_RTOL}"
-    )
-    check(rel <= PALLAS_LOSS_RTOL, f"Pallas loss off by {rel:.3e} > {PALLAS_LOSS_RTOL}")
+    xla = state["federated-ac"]
+    for rec, ref_rec in zip(run["records"], xla["records"], strict=True):
+        loss, ref = rec["mean_local_loss"], ref_rec["mean_local_loss"]
+        rel = abs(loss - ref) / abs(ref)
+        rtol = PALLAS_LOSS_RTOL if rec["round_index"] == 0 else PALLAS_DRIFT_RTOL
+        log(
+            f"  round {rec['round_index']} loss pallas={loss!r} xla_scan={ref!r} "
+            f"rel_diff={rel!r} rtol={rtol}"
+        )
+        check(rel <= rtol, f"Pallas loss off by {rel:.3e} > {rtol}")
+    diff = max_abs_diff(final_params(run["run_dir"]), final_params(xla["run_dir"]))
+    log(f"  final params pallas vs xla_scan: max|diff|={diff!r} atol={PALLAS_PARAM_ATOL}")
+    check(diff <= PALLAS_PARAM_ATOL, f"Pallas params off by {diff:.3e} > {PALLAS_PARAM_ATOL}")
 
 
 def phase_reference() -> None:

@@ -126,7 +126,6 @@ def ssd_chunk_scan(
     b_mat: jnp.ndarray,  # (B, NC, L, N)
     c_mat: jnp.ndarray,  # (B, NC, L, N)
     *,
-    h_tile: int | None = None,
     interpret: bool | None = None,
     return_states: bool = False,
 ):
@@ -135,8 +134,7 @@ def ssd_chunk_scan(
     ``backend.interpret()``."""
     batch, nc, l_len, h, p = x.shape
     n = b_mat.shape[-1]
-    h_tile = h_tile or _pick_h_tile(h)
-    assert h % h_tile == 0, f"h_tile {h_tile} must divide head count {h}"
+    h_tile = _pick_h_tile(h)
     xh, dth, cumh = _heads_major(x, dt, cum)
 
     y_spec = pl.BlockSpec((1, 1, h_tile, l_len, p), lambda b, hh, c: (b, c, hh, 0, 0))
@@ -250,14 +248,12 @@ def ssd_chunk_scan_bwd(
     states: jnp.ndarray,  # (B, NC, H, P, N) fp32 chunk-entry states
     dy: jnp.ndarray,      # (B, NC, L, H, P)
     *,
-    h_tile: int | None = None,
     interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, ...]:
     """Single-pass Pallas backward: ``(dx, ddt, dcum, db, dc)``."""
     batch, nc, l_len, h, p = x.shape
     n = b_mat.shape[-1]
-    h_tile = h_tile or _pick_h_tile(h)
-    assert h % h_tile == 0, f"h_tile {h_tile} must divide head count {h}"
+    h_tile = _pick_h_tile(h)
     tiles = h // h_tile
     xh, dth, cumh = _heads_major(x, dt, cum)
     dyh = jnp.swapaxes(dy, 2, 3)

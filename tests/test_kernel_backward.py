@@ -148,8 +148,7 @@ def test_ssd_chunked_backward_matches_chunk_oracle(dtype, nc, length):
     assert_grads_close(g, g_ref, tol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
 
 
-def test_ssd_pallas_backward_kernel_matches_oracle():
-    b, nc, length, h, p, n = 2, 3, 8, 4, 8, 16
+def _check_ssd_pallas_backward(b, nc, length, h, p, n):
     ks = jax.random.split(jax.random.PRNGKey(3), 6)
     xc = jax.random.normal(ks[0], (b, nc, length, h, p), jnp.float32)
     dtc = jax.nn.softplus(jax.random.normal(ks[1], (b, nc, length, h)))
@@ -164,6 +163,16 @@ def test_ssd_pallas_backward_kernel_matches_oracle():
     assert_grads_close(got, vjp(dy), tol=F32_TOL)
     resid = ssd_chunk_scan_bwd_ref(xc, dtc, cum, bc, cc, states, dy)
     assert_grads_close(resid, vjp(dy), tol=F32_TOL)
+
+
+def test_ssd_pallas_backward_kernel_matches_oracle():
+    _check_ssd_pallas_backward(b=2, nc=3, length=8, h=4, p=8, n=16)
+
+
+def test_ssd_pallas_backward_kernel_multi_head_tile():
+    """H=16 is two 8-head tiles: the per-tile dB/dC partials the wrapper
+    sums, and the dS carry reset for each (batch, tile) pair."""
+    _check_ssd_pallas_backward(b=2, nc=3, length=8, h=16, p=8, n=16)
 
 
 # --------------------------------------------------------------------------

@@ -183,7 +183,8 @@ SSD_SHAPES = [
     (2, 64, 4, 16, 32, 16),
     (1, 37, 2, 8, 16, 16),    # ragged seq vs chunk
     (3, 128, 8, 32, 64, 32),
-    (2, 96, 3, 16, 16, 32),   # h not divisible by 4 -> h_tile fallback
+    (2, 96, 3, 16, 16, 32),   # h not a multiple of 8 -> one tile of all heads
+    (2, 48, 16, 8, 16, 16),   # two 8-head tiles
 ]
 
 
