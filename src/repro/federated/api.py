@@ -894,13 +894,15 @@ class Federation:
 
     def _train_group(
         self, params: PyTree, group: np.ndarray, rng, jax_rng, spe: int
-    ) -> tuple[PyTree, np.ndarray, int, jax.Array]:
+    ) -> tuple[PyTree, np.ndarray, int, int, jax.Array]:
         """One engine round over ``group``: FedAvg-reduced params.
 
         This is the pre-API hot path, untouched: the vectorized engine
         consumes one ``chain_split_keys`` chunk and streams the weighted
         sum inside its jitted round; the sequential engine splits one key
-        per client and stacks once.
+        per client and stacks once.  Returns the params, per-client
+        losses, real local steps, client-steps scanned (padding included;
+        the sequential engine scans none) and the advanced key.
         """
         cohort = [self.all_clients[int(cid)] for cid in group]
         if self.config.engine == "vectorized":
@@ -908,7 +910,8 @@ class Federation:
             params, per_losses, steps = self.cohort_trainer.train_cohort(
                 params, cohort, rng, key_data, steps_per_epoch=spe
             )
-            return params, per_losses, steps, jax_rng
+            scanned = self.cohort_trainer.last_round_stats["scanned_steps"]
+            return params, per_losses, steps, scanned, jax_rng
         client_params, weights, losses, steps = [], [], [], 0
         for client in cohort:
             jax_rng, sub = jax.random.split(jax_rng)
@@ -919,12 +922,14 @@ class Federation:
             steps += self.trainer.steps_per_round(client)
         stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves), *client_params)
         params = aggregate_stacked(stacked, np.asarray(weights, dtype=np.float32))
-        return params, np.asarray(losses, dtype=np.float32), steps, jax_rng
+        return params, np.asarray(losses, dtype=np.float32), steps, steps, jax_rng
 
     def _train_round(
         self, params: PyTree, participants: np.ndarray, rng, jax_rng, spe: int
-    ) -> tuple[PyTree, np.ndarray, int, jax.Array]:
-        """train -> aggregate for one round, dispatched on the aggregator mode."""
+    ) -> tuple[PyTree, np.ndarray, int, int, jax.Array]:
+        """train -> aggregate for one round, dispatched on the aggregator mode.
+
+        Returns what :meth:`_train_group` does, summed over the groups."""
         mode = self.aggregator.mode
         if mode == "reduced":
             return self._train_group(params, participants, rng, jax_rng, spe)
@@ -934,15 +939,16 @@ class Federation:
             flat = np.concatenate([np.asarray(g) for g in groups]) if groups else np.array([])
             if sorted(flat.tolist()) != sorted(np.asarray(participants).tolist()):
                 raise ValueError("aggregator groups must partition the participants")
-            group_params, group_w, losses, steps = [], [], [], 0
+            group_params, group_w, losses, steps, scanned = [], [], [], 0, 0
             for group in groups:
-                p_g, losses_g, steps_g, jax_rng = self._train_group(
+                p_g, losses_g, steps_g, scanned_g, jax_rng = self._train_group(
                     params, group, rng, jax_rng, spe
                 )
                 group_params.append(p_g)
                 group_w.append(sum(self.all_clients[int(c)].n_train for c in group))
                 losses.append(losses_g)
                 steps += steps_g
+                scanned += scanned_g
             with self.tracer.span("aggregate", groups=len(groups)):
                 stacked = jax.tree.map(
                     lambda *leaves: jnp.stack(leaves), *group_params
@@ -950,7 +956,7 @@ class Federation:
                 new_params = self.aggregator.aggregate(
                     stacked, np.asarray(group_w, dtype=np.float32)
                 )
-            return new_params, np.concatenate(losses), steps, jax_rng
+            return new_params, np.concatenate(losses), steps, scanned, jax_rng
 
         # mode == "stacked": the aggregator needs every client's params, which
         # the vectorized engine's in-jit reduction never materializes — these
@@ -971,18 +977,21 @@ class Federation:
             new_params = self.aggregator.aggregate(
                 stacked, np.asarray(weights, dtype=np.float32)
             )
-        return new_params, np.asarray(losses, dtype=np.float32), steps, jax_rng
+        return new_params, np.asarray(losses, dtype=np.float32), steps, steps, jax_rng
 
     # -- observability --------------------------------------------------------
 
-    def _absorb_round_metrics(self, record: RoundRecord) -> None:
+    def _absorb_round_metrics(
+        self, record: RoundRecord, scanned_steps: int | None = None
+    ) -> None:
         """Fold a finished round into the metrics registry.
 
         Absorbs the comms accounting and per-round loss from the record
         plus the cohort engine's ad-hoc ``last_round_stats`` dict (staged
         bytes, prefetch hits, pool uploads/evictions) into the typed
         counters/gauges/histograms the control plane streams as
-        ``metrics.jsonl``.
+        ``metrics.jsonl``.  ``scanned_steps`` (sync rounds) feeds
+        ``train.scanned_steps``.
         """
         m = self.metrics
         m.counter("rounds.completed").inc()
@@ -993,6 +1002,8 @@ class Federation:
             record.bytes_transferred - record.bytes_transferred // 2
         )
         m.counter("train.local_steps").inc(record.local_steps)
+        if scanned_steps is not None:
+            m.counter("train.scanned_steps").inc(scanned_steps)
         m.histogram("round.time_s").observe(record.wall_time_s)
         if np.isfinite(record.mean_local_loss):
             m.histogram("round.loss").observe(record.mean_local_loss)
@@ -1102,7 +1113,7 @@ class Federation:
         tracer = self.tracer
         t_start = time.perf_counter()
 
-        with CompileWatcher(self.metrics) as watcher:
+        with CompileWatcher(self.metrics, tracer) as watcher:
             for rnd in range(start_round, cfg.rounds):
                 if self.profiler is not None:
                     self.profiler.round_start(rnd)
@@ -1122,7 +1133,7 @@ class Federation:
                 with tracer.span(
                     "train", round=rnd, participants=len(participants)
                 ):
-                    params, losses, steps, jax_rng = self._train_round(
+                    params, losses, steps, scanned, jax_rng = self._train_round(
                         params, participants, rng, jax_rng, federation_spe
                     )
                 self.selection_policy.observe(participants, losses)
@@ -1150,12 +1161,15 @@ class Federation:
                     dur=wall,
                     round=rnd,
                     participants=len(participants),
+                    local_steps=steps,
+                    scanned_steps=scanned,
                 )
                 history.append(record)
-                watcher.poll()
-                self._absorb_round_metrics(record)
-                if progress is not None:
-                    progress(record)
+                with tracer.span("record", round=rnd):
+                    watcher.poll()
+                    self._absorb_round_metrics(record, scanned)
+                    if progress is not None:
+                        progress(record)
                 if snapshot_hook is not None:
                     with tracer.span("checkpoint", round=rnd):
                         snapshot_hook(

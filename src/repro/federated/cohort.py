@@ -500,33 +500,40 @@ class CohortTrainer:
         across rounds — otherwise it tracks this cohort's largest client and
         a different participant mix can retrigger compilation.  Returns the
         round's aggregated params, per-client mean local losses, and the
-        number of *real* (unpadded) local steps executed.
+        number of *real* (unpadded) local steps executed;
+        ``last_round_stats["scanned_steps"]`` counts the client-steps the
+        scan ran, padding clients and steps included.
         """
-        all_key_data = self._stack_key_data(client_keys)
-        if len(clients) != len(all_key_data):
-            raise ValueError("need exactly one PRNG key per client")
-        sizes = [c.n_train for c in clients]
-        spe = steps_per_epoch or cohort_steps_per_epoch(sizes, self.batch_size)
-        if self.cohort_chunk is not None and self.cohort_chunk <= 0:
-            raise ValueError(f"cohort_chunk must be positive, got {self.cohort_chunk}")
-        chunk = self.cohort_chunk or len(clients)
-        resident = self.staging == "resident"
-        dcohort = self._ensure_device_cohort(clients) if resident else None
-        pool_before = (0, 0, 0, 0)
-        if resident and dcohort.is_pooled:
-            # One residency pass per round, before any plan is staged: rows
-            # are then stable for the whole round, so the prefetch thread's
-            # plan building never races an eviction.
-            pool_before = (
-                dcohort.uploads,
-                dcohort.evictions,
-                dcohort.bytes_uploaded,
-                dcohort.hits,
-            )
-            dcohort.ensure_resident(clients)
+        tracer = self.tracer
+        with tracer.span("prepare", clients=len(clients)):
+            all_key_data = self._stack_key_data(client_keys)
+            if len(clients) != len(all_key_data):
+                raise ValueError("need exactly one PRNG key per client")
+            sizes = [c.n_train for c in clients]
+            spe = steps_per_epoch or cohort_steps_per_epoch(sizes, self.batch_size)
+            if self.cohort_chunk is not None and self.cohort_chunk <= 0:
+                raise ValueError(f"cohort_chunk must be positive, got {self.cohort_chunk}")
+            chunk = self.cohort_chunk or len(clients)
+            resident = self.staging == "resident"
+            dcohort = self._ensure_device_cohort(clients) if resident else None
+            pool_before = (0, 0, 0, 0)
+            if resident and dcohort.is_pooled:
+                # One residency pass per round, before any plan is staged:
+                # rows are then stable for the whole round, so the prefetch
+                # thread's plan building never races an eviction.
+                pool_before = (
+                    dcohort.uploads,
+                    dcohort.evictions,
+                    dcohort.bytes_uploaded,
+                    dcohort.hits,
+                )
+                dcohort.ensure_resident(clients)
 
-        baseline = live_buffer_stats() if self.track_stats else {"count": 0, "bytes": 0}
-        peak = {"count": 0, "bytes": 0}
+            baseline = live_buffer_stats() if self.track_stats else {"count": 0, "bytes": 0}
+            peak = {"count": 0, "bytes": 0}
+            acc = jax.tree.map(
+                lambda p: jnp.zeros(p.shape, jnp.promote_types(p.dtype, jnp.float32)), params
+            )
 
         def sample() -> None:
             if not self.track_stats:
@@ -535,10 +542,11 @@ class CohortTrainer:
             peak["count"] = max(peak["count"], now["count"] - baseline["count"])
             peak["bytes"] = max(peak["bytes"], now["bytes"] - baseline["bytes"])
 
-        def _build_chunk(start: int) -> tuple[int, float, int, tuple, tuple]:
+        def _build_chunk(start: int) -> tuple[int, float, int, int, tuple, tuple]:
             """Build + upload one chunk's batch data.
 
             Returns (host bytes staged, chunk weight, real client count,
+            client-steps the round's scan runs, padding included,
             (row-select path, slice start), device args for the round
             step).  Consumes ``rng`` — must run strictly in chunk order
             (the StagingPipeline's single ordered producer preserves this).
@@ -596,6 +604,7 @@ class CohortTrainer:
                     to_stage = (plan.client_rows, *to_stage)
                 staged = self._device_put_chunk(to_stage)
                 path = (kind, r0)
+                scanned = int(plan.step_valid.size)  # (clients, steps), padding included
             else:
                 sched = build_cohort_schedule(
                     [c.train for c in part],
@@ -612,6 +621,7 @@ class CohortTrainer:
                     all_key_data, start, len(part), sched.num_clients
                 )
                 path = ("gather", 0)
+                scanned = int(sched.step_valid.size)
                 host = (sched.x, sched.y, sched.mask, sched.step_valid, sched.weights)
                 staged = self._device_put_chunk(
                     (sched.x, sched.y, sched.mask, sched.step_valid, key_data, sched.weights)
@@ -619,19 +629,17 @@ class CohortTrainer:
             nbytes = sum(a.nbytes for a in host)
             if isinstance(key_data, np.ndarray):
                 nbytes += key_data.nbytes
-            return nbytes, weight, len(part), path, staged
+            return nbytes, weight, len(part), scanned, path, staged
 
-        def stage_chunk(start: int) -> tuple[int, float, int, tuple, tuple]:
+        def stage_chunk(start: int) -> tuple[int, float, int, int, tuple, tuple]:
             # The span lands on whichever thread stages — inline here, or
             # the StagingPipeline's producer during prefetch.
-            with self.tracer.span("stage", track="staging", chunk=int(start)):
+            with tracer.span("stage", track="staging", chunk=int(start)):
                 return _build_chunk(start)
 
-        acc = jax.tree.map(
-            lambda p: jnp.zeros(p.shape, jnp.promote_types(p.dtype, jnp.float32)), params
-        )
         total_weight = 0.0
         bytes_staged = 0
+        scanned_steps = 0
         num_chunks = 0
         # Per-chunk device loss arrays; fetched once after the whole round
         # is dispatched so chunk k+1 never blocks on chunk k's readback.
@@ -639,7 +647,7 @@ class CohortTrainer:
         starts = range(0, len(clients), chunk)
         pipeline: StagingPipeline | None = None
         if resident and self.prefetch and len(starts) > 1:
-            pipeline = StagingPipeline(stage_chunk, starts, tracer=self.tracer)
+            pipeline = StagingPipeline(stage_chunk, starts, tracer=tracer)
             staged_chunks = iter(pipeline)
         else:
             staged_chunks = (stage_chunk(s) for s in starts)
@@ -650,38 +658,44 @@ class CohortTrainer:
         held: list[tuple] = []
         slice_chunks = 0
         try:
-            for start, (nbytes, weight, count, path, args) in zip(starts, staged_chunks):
+            for start, (nbytes, weight, count, scanned, path, args) in zip(
+                starts, staged_chunks
+            ):
                 total_weight += weight
                 bytes_staged += nbytes
+                scanned_steps += scanned
                 # Sampled before the previous chunk's buffers (still
                 # referenced by ``held`` on the non-donated path) are
                 # released: the plain rebuild path holds two chunks of
                 # schedule here, the donated path one.
                 sample()
                 held.clear()
-                if resident:
-                    kind, r0 = path
-                    if kind == "full":
-                        acc, losses = self._round_full(
-                            params, acc, dcohort.x, dcohort.y, *args
-                        )
-                    elif kind == "slice":
-                        slice_chunks += 1
-                        acc, losses = self._round_slice(
-                            params, acc, dcohort.x, dcohort.y, *args, r0
-                        )
+                with tracer.span("dispatch", chunk=int(start)):
+                    if resident:
+                        kind, r0 = path
+                        if kind == "full":
+                            acc, losses = self._round_full(
+                                params, acc, dcohort.x, dcohort.y, *args
+                            )
+                        elif kind == "slice":
+                            slice_chunks += 1
+                            acc, losses = self._round_slice(
+                                params, acc, dcohort.x, dcohort.y, *args, r0
+                            )
+                        else:
+                            acc, losses = self._round(
+                                params, acc, dcohort.x, dcohort.y, *args
+                            )
                     else:
-                        acc, losses = self._round(params, acc, dcohort.x, dcohort.y, *args)
-                else:
-                    acc, losses = self._round(params, acc, *args)
-                if self.donate:
-                    # Realize the donation of the staged chunk: the step
-                    # consumed it, free the device copies now instead of at
-                    # Python GC time.  The resident cohort arrays are not
-                    # part of ``args`` and stay alive.
-                    for a in args:
-                        if not a.is_deleted():
-                            a.delete()
+                        acc, losses = self._round(params, acc, *args)
+                    if self.donate:
+                        # Realize the donation of the staged chunk: the step
+                        # consumed it, free the device copies now instead of
+                        # at Python GC time.  The resident cohort arrays are
+                        # not part of ``args`` and stay alive.
+                        for a in args:
+                            if not a.is_deleted():
+                                a.delete()
                 sample()
                 chunk_losses.append((start, count, losses))
                 held.append(args)
@@ -693,34 +707,39 @@ class CohortTrainer:
                 # mask the error that aborted the loop above.
                 pipeline.close(raise_pending=sys.exc_info()[0] is None)
 
-        per_losses = np.full(len(clients), np.nan, dtype=np.float32)
-        for start, count, losses in chunk_losses:
-            per_losses[start : start + count] = np.asarray(losses)[:count]
+        with tracer.span("readback", chunks=len(chunk_losses)):
+            per_losses = np.full(len(clients), np.nan, dtype=np.float32)
+            for start, count, losses in chunk_losses:
+                per_losses[start : start + count] = np.asarray(losses)[:count]
 
-        new_params = jax.tree.map(
-            lambda t, ref: (t / total_weight).astype(ref.dtype), acc, params
-        )
-        pooled = resident and dcohort.is_pooled
-        self.last_round_stats = {
-            "chunks": num_chunks,
-            "shards": self._num_shards,
-            "donated": self.donate,
-            "staging": self.staging,
-            "prefetch": pipeline is not None,
-            "bytes_staged": bytes_staged,
-            "bytes_resident": dcohort.nbytes if resident else 0,
-            "plans_prefetched": pipeline.prefetched if pipeline is not None else 0,
-            "peak_live_buffers": peak["count"],
-            "peak_live_bytes": peak["bytes"],
-            "slice_chunks": slice_chunks,
-            "pool": pooled,
-            "pool_rows": dcohort.pool_rows if pooled else 0,
-            "pool_uploads": dcohort.uploads - pool_before[0] if pooled else 0,
-            "pool_evictions": dcohort.evictions - pool_before[1] if pooled else 0,
-            "pool_bytes_uploaded": dcohort.bytes_uploaded - pool_before[2] if pooled else 0,
-            "pool_hits": dcohort.hits - pool_before[3] if pooled else 0,
-        }
-        real_steps = sum(local_round_steps(n, self.batch_size, self.local_epochs) for n in sizes)
+        with tracer.span("finalize"):
+            new_params = jax.tree.map(
+                lambda t, ref: (t / total_weight).astype(ref.dtype), acc, params
+            )
+            pooled = resident and dcohort.is_pooled
+            self.last_round_stats = {
+                "chunks": num_chunks,
+                "shards": self._num_shards,
+                "donated": self.donate,
+                "staging": self.staging,
+                "prefetch": pipeline is not None,
+                "bytes_staged": bytes_staged,
+                "bytes_resident": dcohort.nbytes if resident else 0,
+                "plans_prefetched": pipeline.prefetched if pipeline is not None else 0,
+                "peak_live_buffers": peak["count"],
+                "peak_live_bytes": peak["bytes"],
+                "slice_chunks": slice_chunks,
+                "scanned_steps": scanned_steps,
+                "pool": pooled,
+                "pool_rows": dcohort.pool_rows if pooled else 0,
+                "pool_uploads": dcohort.uploads - pool_before[0] if pooled else 0,
+                "pool_evictions": dcohort.evictions - pool_before[1] if pooled else 0,
+                "pool_bytes_uploaded": dcohort.bytes_uploaded - pool_before[2] if pooled else 0,
+                "pool_hits": dcohort.hits - pool_before[3] if pooled else 0,
+            }
+            real_steps = sum(
+                local_round_steps(n, self.batch_size, self.local_epochs) for n in sizes
+            )
         return new_params, per_losses, real_steps
 
     def steps_per_round(self, client: ClientDataset) -> int:

@@ -571,7 +571,7 @@ class AsyncFederation:
             update = None
             with tracer.span("dispatch", group=group_index, latency=latency):
                 if len(survivors):
-                    task_params, losses, steps, jax_rng = self._fed._train_group(
+                    task_params, losses, steps, _, jax_rng = self._fed._train_group(
                         params, survivors, rng, jax_rng, spe
                     )
                     stats["steps_trained"] += steps
@@ -680,7 +680,7 @@ class AsyncFederation:
                 return False
             return True
 
-        with CompileWatcher(self.metrics) as watcher:
+        with CompileWatcher(self.metrics, self.tracer) as watcher:
             dispatch_ready()
             while True:
                 if sched.empty:

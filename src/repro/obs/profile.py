@@ -7,10 +7,12 @@ Two optional instruments, both wired through the job spec's strict
   first N rounds of a run (the designated rounds), writing TensorBoard-
   loadable artifacts under ``<run_dir>/jax_profile``.
 - :class:`CompileWatcher` registers a ``jax.monitoring`` listener and
-  counts compile events and their durations, surfacing them as
-  ``jit.compiles`` / ``jit.compile_time_s`` counters and a per-round
-  ``jit.round_compiles`` gauge — hot-path recompilation becomes an
-  assertable regression rather than a silent slowdown.
+  counts backend compile requests (an XLA compile or a persistent-cache
+  load) and their seconds, surfacing them as ``jit.compiles`` /
+  ``jit.compile_time_s`` counters and a per-round ``jit.round_compiles``
+  gauge — hot-path recompilation becomes an assertable regression rather
+  than a silent slowdown — and, on a traced run, as ``compile`` spans
+  naming the program.
 
 Neither swallows a failure: a profiler that cannot start or stop, or a
 listener that cannot register, raises into the run that asked for it, so a
@@ -25,6 +27,7 @@ import dataclasses
 from typing import Any, Mapping
 
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import Tracer, resolve_tracer
 
 # Defaults for the job spec's ``observability`` section.  ``None`` for the
 # section itself means "observability off" (same tri-state contract as the
@@ -109,6 +112,11 @@ class RoundProfiler:
         jax.profiler.stop_trace()
 
 
+# The event jax records around every backend compile request: an XLA
+# compile, or a load of the compiled program from the persistent cache
+# (either stalls the caller).  Its duration listeners get ``fun_name``.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
 # One process-global jax.monitoring listener fans out to live watchers;
 # jax exposes register but no public unregister, so the listener is
 # installed once and consults this list.
@@ -121,32 +129,29 @@ def _install_listener() -> None:
         return
     import jax.monitoring
 
-    def on_event(event: str, **kw: Any) -> None:
-        if "compile" in event:
-            for watcher in _ACTIVE_WATCHERS:
-                watcher.compiles += 1
+    def on_duration(event: str, duration: float, fun_name: str = "", **kw: Any) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            for watcher in list(_ACTIVE_WATCHERS):
+                watcher.record(duration, fun_name)
 
-    def on_duration(event: str, duration: float, **kw: Any) -> None:
-        if "compile" in event:
-            for watcher in _ACTIVE_WATCHERS:
-                watcher.compile_time_s += duration
-
-    jax.monitoring.register_event_listener(on_event)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     _LISTENER_STATE["installed"] = True
 
 
 class CompileWatcher:
-    """Count jax compile events/durations while active; feed a registry.
+    """Count backend compile requests and their seconds while active.
 
     Used as a context manager around a run's round loop; ``poll`` after
     each round folds deltas into ``jit.compiles`` / ``jit.compile_time_s``
     counters and sets the ``jit.round_compiles`` gauge so a steady-state
-    round recompiling shows up as a nonzero gauge.
+    round recompiling shows up as a nonzero gauge.  With an enabled
+    ``tracer`` each request is also a ``compile`` span on its own track,
+    ending when jax reports it and carrying ``fun``, the program's name.
     """
 
-    def __init__(self, metrics: MetricsRegistry | None):
+    def __init__(self, metrics: MetricsRegistry | None, tracer: Tracer | None = None):
         self.metrics = metrics
+        self.tracer = resolve_tracer(tracer)
         self.compiles = 0
         self.compile_time_s = 0.0
         self._polled_compiles = 0
@@ -160,6 +165,20 @@ class CompileWatcher:
     def __exit__(self, *exc_info: object) -> None:
         if self in _ACTIVE_WATCHERS:
             _ACTIVE_WATCHERS.remove(self)
+
+    def record(self, duration: float, fun_name: str) -> None:
+        """One backend compile request of ``duration`` seconds, ending now."""
+        self.compiles += 1
+        self.compile_time_s += duration
+        tracer = self.tracer
+        if tracer.enabled:
+            tracer.complete(
+                "compile",
+                start=tracer.now() - duration,
+                dur=duration,
+                track="compile",
+                fun=str(fun_name),
+            )
 
     def poll(self) -> int:
         """Fold deltas since the last poll into the registry; return delta."""

@@ -18,6 +18,12 @@ visible side by side; async tasks land on per-client tracks with flow
 arrows from their dispatch, which makes straggler and dropout schedules
 visually inspectable.
 
+Each ``span`` of an enabled tracer is also a ``jax.profiler`` annotation
+``repro.<name>`` while its body runs, so a profiler capture (the
+benchmark's traced window, ``jax_profile_rounds``) puts the program's
+phases on the device trace's clock.  ``complete`` spans (``round``,
+``compile``) are recorded after the fact and are not annotations.
+
 The default tracer everywhere is :data:`NULL_TRACER`, whose methods are
 no-ops and whose ``span`` context manager is a shared singleton — the
 instrumented-off overhead is a handful of attribute lookups per round.
@@ -29,7 +35,7 @@ import dataclasses
 import json
 import time
 from collections import deque
-from typing import Any, Callable, Iterable
+from typing import Any, Iterable
 
 HOST_CLOCK = "host"
 VIRTUAL_CLOCK = "virtual"
@@ -60,9 +66,15 @@ class SpanEvent:
 
 
 class _SpanContext:
-    """Context manager that records a host-clock complete span on exit."""
+    """Context manager that records a host-clock complete span on exit.
 
-    __slots__ = ("_tracer", "_name", "_track", "_args", "_start")
+    For the body's lifetime it also enters a ``jax.profiler`` annotation
+    named ``repro.<name>`` (with the span's int and str args), so any
+    profiler capture shows the span beside the device's ops on one clock.
+    Outside a capture the annotation costs well under a microsecond.
+    """
+
+    __slots__ = ("_tracer", "_name", "_track", "_args", "_start", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, track: str, args: dict[str, Any] | None):
         self._tracer = tracer
@@ -70,20 +82,30 @@ class _SpanContext:
         self._track = track
         self._args = args
         self._start = 0.0
+        self._annotation: Any = None
 
     def __enter__(self) -> "_SpanContext":
+        import jax.profiler  # lazy: repro.obs imports without jax
+
+        stats = {k: v for k, v in (self._args or {}).items() if isinstance(v, (int, str))}
+        annotation = jax.profiler.TraceAnnotation(f"repro.{self._name}", **stats)
+        annotation.__enter__()
+        self._annotation = annotation
         self._start = self._tracer.now()
         return self
 
     def __exit__(self, *exc_info: object) -> None:
         tracer = self._tracer
-        tracer.complete(
-            self._name,
-            start=self._start,
-            dur=tracer.now() - self._start,
-            track=self._track,
-            **(self._args or {}),
-        )
+        try:
+            tracer.complete(
+                self._name,
+                start=self._start,
+                dur=tracer.now() - self._start,
+                track=self._track,
+                **(self._args or {}),
+            )
+        finally:
+            self._annotation.__exit__(*exc_info)
 
 
 class _NullContext:
@@ -139,20 +161,6 @@ class Tracer:
     def span(self, name: str, track: str = "server", **args: Any) -> _SpanContext:
         """Context manager recording a host-clock span around the body."""
         return _SpanContext(self, name, track, args or None)
-
-    def wrap(self, name: str, track: str = "server") -> Callable:
-        """Decorator form of :meth:`span`."""
-
-        def decorate(fn: Callable) -> Callable:
-            def wrapped(*a: Any, **kw: Any) -> Any:
-                with self.span(name, track=track):
-                    return fn(*a, **kw)
-
-            wrapped.__name__ = getattr(fn, "__name__", name)
-            wrapped.__doc__ = fn.__doc__
-            return wrapped
-
-        return decorate
 
     def complete(
         self,
@@ -259,12 +267,6 @@ class NullTracer(Tracer):
 
     def flow_end(self, name: str, flow_id: int, **kw: Any) -> None:  # type: ignore[override]
         return None
-
-    def wrap(self, name: str, track: str = "server") -> Callable:  # type: ignore[override]
-        def decorate(fn: Callable) -> Callable:
-            return fn
-
-        return decorate
 
 
 NULL_TRACER = NullTracer()

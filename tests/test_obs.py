@@ -59,6 +59,30 @@ def setup():
     return clients, make_loss_fn(cfg), init_gru(jax.random.key(1), cfg)
 
 
+class _RecordedAnnotation:
+    def __init__(self, log, name, **kwargs):
+        self.name, self.kwargs, self.state = name, kwargs, "made"
+        log.append(self)
+
+    def __enter__(self):
+        self.state = "open"
+        return self
+
+    def __exit__(self, *exc):
+        self.state = "closed"
+
+
+def record_annotations(monkeypatch) -> list:
+    """Replace ``jax.profiler.TraceAnnotation`` with a recorder."""
+    import jax.profiler
+
+    log: list = []
+    monkeypatch.setattr(
+        jax.profiler, "TraceAnnotation", lambda name, **kw: _RecordedAnnotation(log, name, **kw)
+    )
+    return log
+
+
 # ---------------------------------------------------------------------------
 # tracer unit behaviour
 # ---------------------------------------------------------------------------
@@ -91,18 +115,38 @@ class TestTracer:
         with pytest.raises(ValueError, match="capacity"):
             Tracer(capacity=0)
 
-    def test_wrap_decorator(self):
+    def test_span_enters_one_annotation_per_span(self, monkeypatch):
+        entered = record_annotations(monkeypatch)
         tracer = Tracer()
+        with tracer.span("outer", track="t", round=3, kind="x", latency=0.5):
+            with tracer.span("inner"):
+                assert [a.state for a in entered] == ["open", "open"]
+        assert [(a.name, a.kwargs, a.state) for a in entered] == [
+            # only int and str args become the annotation's stats
+            ("repro.outer", {"round": 3, "kind": "x"}, "closed"),
+            ("repro.inner", {}, "closed"),
+        ]
+        # trace.json's events are what they were: the span keeps every arg
+        assert tracer.spans("outer")[0].args == {"round": 3, "kind": "x", "latency": 0.5}
 
-        @tracer.wrap("work", track="w")
-        def work(x):
-            """doc"""
-            return x + 1
+    def test_annotation_closes_when_the_body_raises(self, monkeypatch):
+        entered = record_annotations(monkeypatch)
+        tracer = Tracer()
+        with pytest.raises(RuntimeError, match="boom"):
+            with tracer.span("checkpoint", round=1):
+                raise RuntimeError("boom")
+        assert [(a.name, a.state) for a in entered] == [("repro.checkpoint", "closed")]
+        assert [s.name for s in tracer.spans()] == ["checkpoint"]
 
-        assert work(2) == 3
-        assert work.__name__ == "work"
-        assert work.__doc__ == "doc"
-        assert [s.name for s in tracer.spans()] == ["work"]
+    def test_null_tracer_enters_no_annotation(self, monkeypatch):
+        from repro.obs.trace import _NULL_CONTEXT
+
+        entered = record_annotations(monkeypatch)
+        ctx = NULL_TRACER.span("select", round=0)
+        assert ctx is _NULL_CONTEXT
+        with ctx:
+            pass
+        assert entered == []
 
     def test_null_tracer_is_inert(self):
         null = resolve_tracer(None)
@@ -116,12 +160,6 @@ class TestTracer:
         null.flow_start("x", 0, ts=0.0)
         null.flow_end("x", 0, ts=0.0, track="t")
         assert null.events() == []
-
-        @null.wrap("x")
-        def fn():
-            return 7
-
-        assert fn() == 7
         tracer = Tracer()
         assert resolve_tracer(tracer) is tracer
 
@@ -281,9 +319,34 @@ class TestCompileWatcher:
             assert watcher.poll() == 1
 
     def test_counts_a_real_compile(self):
+        x = jnp.arange(7.0).block_until_ready()
+
+        def times_three_plus_one(v):
+            return v * 3 + 1
+
+        fn = jax.jit(times_three_plus_one)
         with CompileWatcher(MetricsRegistry()) as watcher:
-            jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
-        assert watcher.compiles >= 1
+            fn(x).block_until_ready()
+            assert watcher.compiles == 1
+            assert watcher.compile_time_s > 0
+            fn(x).block_until_ready()  # the cached executable: no request
+        assert watcher.compiles == 1
+
+    def test_traced_compile_span_names_the_program(self):
+        x = jnp.arange(5.0).block_until_ready()
+
+        def plus_seven(v):
+            return v + 7
+
+        tracer = Tracer()
+        with CompileWatcher(None, tracer) as watcher:
+            jax.jit(plus_seven)(x).block_until_ready()
+        (span,) = tracer.spans("compile")
+        assert watcher.compiles == 1
+        assert span.track == "compile"
+        assert "plus_seven" in span.args["fun"]
+        assert span.dur == pytest.approx(watcher.compile_time_s)
+        assert 0 <= span.ts <= tracer.now() - span.dur
 
 
 class TestRoundProfiler:
@@ -388,6 +451,101 @@ class TestTracedFederation:
         ] == sum(r.bytes_transferred for r in out.history)
         assert snap["histograms"]["round.time_s"]["count"] == 3
         assert out.summary()["metrics"] == snap
+
+    def test_round_phases_have_spans(self, setup):
+        """Each round: select, train (prepare, stage, dispatch, readback,
+        finalize inside it), then after the round's end its record and
+        checkpoint; nothing inside checkpoint or stage."""
+        clients, loss_fn, params0 = setup
+        tracer = Tracer()
+        fed = Federation(
+            FederationConfig(rounds=3, local_epochs=1, batch_size=4, seed=0),
+            clients, loss_fn, AdamW(learning_rate=5e-3),
+            tracer=tracer,
+        )
+        fed.run(params0, snapshot_hook=lambda snap: None)
+        by_name = {}
+        for s in tracer.spans():
+            by_name.setdefault(s.name, []).append(s)
+        for name in ("select", "train", "prepare", "stage", "dispatch", "readback",
+                     "finalize", "record", "checkpoint"):
+            assert len(by_name[name]) == 3, name
+
+        def inside(inner, outer):
+            return outer.ts <= inner.ts and inner.ts + inner.dur <= outer.ts + outer.dur
+
+        for k in range(3):
+            rnd, train = by_name["round"][k], by_name["train"][k]
+            for name in ("prepare", "stage", "dispatch", "readback", "finalize"):
+                assert inside(by_name[name][k], train), (name, k)
+            order = [by_name[n][k] for n in ("prepare", "stage", "dispatch", "readback", "finalize")]
+            assert all(a.ts + a.dur <= b.ts for a, b in zip(order, order[1:]))
+            record, ckpt = by_name["record"][k], by_name["checkpoint"][k]
+            assert record.args["round"] == ckpt.args["round"] == k
+            assert rnd.ts + rnd.dur <= record.ts
+            assert record.ts + record.dur <= ckpt.ts
+        for outer in by_name["checkpoint"] + by_name["stage"]:
+            nested = [s for s in tracer.spans() if s is not outer and inside(s, outer)]
+            assert nested == [], (outer.name, [s.name for s in nested])
+
+    def test_round_span_counts_scanned_steps(self, setup):
+        clients, loss_fn, params0 = setup
+        tracer = Tracer()
+        fed = Federation(
+            FederationConfig(rounds=2, local_epochs=2, batch_size=4, seed=0),
+            clients, loss_fn, AdamW(learning_rate=5e-3),
+            tracer=tracer,
+        )
+        out = fed.run(params0)
+        rounds = tracer.spans("round")
+        spe = max(math.ceil(c.n_train / 4) for c in clients)
+        for span, record in zip(rounds, out.history):
+            assert span.args["local_steps"] == record.local_steps
+            # every client scans the federation's largest step axis
+            assert span.args["scanned_steps"] == len(clients) * 2 * spe
+            assert span.args["scanned_steps"] >= span.args["local_steps"]
+        counters = out.metrics["counters"]
+        assert counters["train.scanned_steps"] == sum(s.args["scanned_steps"] for s in rounds)
+        assert counters["train.local_steps"] == out.total_local_steps
+
+    @pytest.mark.parametrize("overrides", [{"engine": "sequential"}, {"aggregator": "trimmed-mean:0.1"}])
+    def test_unpadded_paths_scan_their_local_steps(self, setup, overrides):
+        clients, loss_fn, params0 = setup
+        tracer = Tracer()
+        fed = Federation(
+            FederationConfig(rounds=1, local_epochs=1, batch_size=4, seed=0, **overrides),
+            clients, loss_fn, AdamW(learning_rate=5e-3),
+            tracer=tracer,
+        )
+        out = fed.run(params0)
+        (span,) = tracer.spans("round")
+        assert span.args["scanned_steps"] == span.args["local_steps"] == out.total_local_steps
+        assert out.metrics["counters"]["train.scanned_steps"] == out.total_local_steps
+
+    def test_spans_land_in_a_profiler_capture(self, setup, tmp_path, monkeypatch):
+        """The program's spans are annotations on the profiler's host plane."""
+        import jax.profiler
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        monkeypatch.syspath_prepend(os.path.join(repo, "bench"))
+        import trace_reduce
+
+        clients, loss_fn, params0 = setup
+        fed = Federation(
+            FederationConfig(rounds=2, local_epochs=1, batch_size=4, seed=0),
+            clients, loss_fn, AdamW(learning_rate=5e-3),
+            tracer=Tracer(),
+        )
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            fed.run(params0, snapshot_hook=lambda snap: None)
+        finally:
+            jax.profiler.stop_trace()
+        events = trace_reduce.events_from_xplane(trace_reduce.find_xplane(str(tmp_path)))
+        names = [e[0].split("#", 1)[0] for e in events["host"]]
+        assert names.count("repro.checkpoint") == 2
+        assert names.count("repro.dispatch") == 2
+        assert "repro.round" not in names  # the round span is not an annotation
 
     def test_stacked_aggregate_span(self, setup):
         clients, loss_fn, params0 = setup
