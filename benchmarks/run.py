@@ -258,10 +258,9 @@ def bench_pipeline(
         "facade_overhead": run_facade_overhead(),
     }
     if mesh_auto and jax.device_count() > 1:
-        # The mesh leg honours cohort_chunk: all-participant chunks are
-        # contiguous resident-row runs, so the static-slice fast path keeps
-        # each shard's rows local instead of the cross-shard gather that
-        # used to force the unchunked fallback here.
+        # The mesh leg honours cohort_chunk: resident rounds train each
+        # client on a lane of the shard that holds its rows, so chunks need
+        # no cross-shard gather.
         report["shard_map"] = run_staging_comparison(
             rounds=rounds, total_stays=total_stays, cohort_chunk=cohort_chunk,
             mesh="auto", variants=("rebuild", "rebuild-chunked", "resident"),

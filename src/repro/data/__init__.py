@@ -1,9 +1,8 @@
 from repro.data.device_cohort import (
-    CohortPlan,
     DeviceCohort,
-    build_cohort_plan,
+    LanePlan,
     build_device_cohort,
-    pad_cohort_plan,
+    build_lane_plan,
 )
 from repro.data.pipeline import (
     ArrayDataset,
@@ -17,12 +16,11 @@ from repro.data.synth_eicu import Cohort, CohortConfig, generate_cohort
 __all__ = [
     "ArrayDataset",
     "ClientDataset",
-    "CohortPlan",
     "DeviceCohort",
+    "LanePlan",
     "build_client_datasets",
-    "build_cohort_plan",
     "build_device_cohort",
-    "pad_cohort_plan",
+    "build_lane_plan",
     "global_dataset",
     "lm_token_batch",
     "Cohort",

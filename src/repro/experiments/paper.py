@@ -416,10 +416,9 @@ def run_staging_comparison(
         from repro.launch.mesh import make_data_mesh
 
         mesh = make_data_mesh() if jax.device_count() > 1 else None
-    # Chunking stays on under a mesh: an all-participant round's chunks are
-    # contiguous runs of resident rows, so the engine's static-slice fast
-    # path selects them without the cross-shard gather that used to force
-    # cohort_chunk=None here.
+    # Chunking stays on under a mesh: resident rounds train each client on
+    # a lane of the shard that holds its rows, so no chunk needs a
+    # cross-shard gather.
     configs: dict[str, dict[str, Any]] = {
         "rebuild": {"staging": "rebuild", "cohort_chunk": None},
         "rebuild-chunked": {"staging": "rebuild", "cohort_chunk": cohort_chunk},

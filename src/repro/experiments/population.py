@@ -142,6 +142,7 @@ def run_population_scale(
     """
     import jax
 
+    from repro.data.device_cohort import resident_row_bytes
     from repro.federated.cohort import CohortTrainer, chain_split_keys
     from repro.models.gru import GRUConfig, init_gru, make_loss_fn
     from repro.optim.adamw import AdamW
@@ -150,8 +151,7 @@ def run_population_scale(
     loss_fn = make_loss_fn(model_cfg)
     params0 = init_gru(jax.random.key(seed), model_cfg)
     n_max = N_RANGE[1] - 1
-    row_bytes = (n_max + 1) * SEQ_LEN * FEAT * 4 + (n_max + 1) * 4
-    budget = pool_rows * row_bytes
+    budget = pool_rows * resident_row_bytes(n_max + 1, (SEQ_LEN, FEAT))
     # steps_per_epoch pinned to the population-wide max so every cohort and
     # every scale reuses one compiled round.
     spe = -(-n_max // BATCH_SIZE)
@@ -243,7 +243,6 @@ def run_population_scale(
                 "pool_evictions_total": dcohort.evictions,
                 "pool_bytes_resident": dcohort.nbytes,
                 "last_round_pool_uploads": stats_round.get("pool_uploads", 0),
-                "slice_chunks_last_round": stats_round.get("slice_chunks", 0),
             }
         )
         entries.append(entry)

@@ -894,15 +894,16 @@ class Federation:
 
     def _train_group(
         self, params: PyTree, group: np.ndarray, rng, jax_rng, spe: int
-    ) -> tuple[PyTree, np.ndarray, int, int, jax.Array]:
+    ) -> tuple[PyTree, np.ndarray, int, tuple[int, int], jax.Array]:
         """One engine round over ``group``: FedAvg-reduced params.
 
         This is the pre-API hot path, untouched: the vectorized engine
         consumes one ``chain_split_keys`` chunk and streams the weighted
         sum inside its jitted round; the sequential engine splits one key
         per client and stacks once.  Returns the params, per-client
-        losses, real local steps, client-steps scanned (padding included;
-        the sequential engine scans none) and the advanced key.
+        losses, real local steps, ``(client-steps scanned, lanes)`` (padding
+        included; the sequential engine pads nothing and trains each client
+        on a lane of its own) and the advanced key.
         """
         cohort = [self.all_clients[int(cid)] for cid in group]
         if self.config.engine == "vectorized":
@@ -910,8 +911,8 @@ class Federation:
             params, per_losses, steps = self.cohort_trainer.train_cohort(
                 params, cohort, rng, key_data, steps_per_epoch=spe
             )
-            scanned = self.cohort_trainer.last_round_stats["scanned_steps"]
-            return params, per_losses, steps, scanned, jax_rng
+            stats = self.cohort_trainer.last_round_stats
+            return params, per_losses, steps, (stats["scanned_steps"], stats["lanes"]), jax_rng
         client_params, weights, losses, steps = [], [], [], 0
         for client in cohort:
             jax_rng, sub = jax.random.split(jax_rng)
@@ -922,11 +923,12 @@ class Federation:
             steps += self.trainer.steps_per_round(client)
         stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves), *client_params)
         params = aggregate_stacked(stacked, np.asarray(weights, dtype=np.float32))
-        return params, np.asarray(losses, dtype=np.float32), steps, steps, jax_rng
+        losses = np.asarray(losses, dtype=np.float32)
+        return params, losses, steps, (steps, len(cohort)), jax_rng
 
     def _train_round(
         self, params: PyTree, participants: np.ndarray, rng, jax_rng, spe: int
-    ) -> tuple[PyTree, np.ndarray, int, int, jax.Array]:
+    ) -> tuple[PyTree, np.ndarray, int, tuple[int, int], jax.Array]:
         """train -> aggregate for one round, dispatched on the aggregator mode.
 
         Returns what :meth:`_train_group` does, summed over the groups."""
@@ -939,9 +941,9 @@ class Federation:
             flat = np.concatenate([np.asarray(g) for g in groups]) if groups else np.array([])
             if sorted(flat.tolist()) != sorted(np.asarray(participants).tolist()):
                 raise ValueError("aggregator groups must partition the participants")
-            group_params, group_w, losses, steps, scanned = [], [], [], 0, 0
+            group_params, group_w, losses, steps, scanned, lanes = [], [], [], 0, 0, 0
             for group in groups:
-                p_g, losses_g, steps_g, scanned_g, jax_rng = self._train_group(
+                p_g, losses_g, steps_g, (scanned_g, lanes_g), jax_rng = self._train_group(
                     params, group, rng, jax_rng, spe
                 )
                 group_params.append(p_g)
@@ -949,6 +951,7 @@ class Federation:
                 losses.append(losses_g)
                 steps += steps_g
                 scanned += scanned_g
+                lanes += lanes_g
             with self.tracer.span("aggregate", groups=len(groups)):
                 stacked = jax.tree.map(
                     lambda *leaves: jnp.stack(leaves), *group_params
@@ -956,7 +959,7 @@ class Federation:
                 new_params = self.aggregator.aggregate(
                     stacked, np.asarray(group_w, dtype=np.float32)
                 )
-            return new_params, np.concatenate(losses), steps, scanned, jax_rng
+            return new_params, np.concatenate(losses), steps, (scanned, lanes), jax_rng
 
         # mode == "stacked": the aggregator needs every client's params, which
         # the vectorized engine's in-jit reduction never materializes — these
@@ -977,7 +980,8 @@ class Federation:
             new_params = self.aggregator.aggregate(
                 stacked, np.asarray(weights, dtype=np.float32)
             )
-        return new_params, np.asarray(losses, dtype=np.float32), steps, steps, jax_rng
+        losses = np.asarray(losses, dtype=np.float32)
+        return new_params, losses, steps, (steps, len(participants)), jax_rng
 
     # -- observability --------------------------------------------------------
 
@@ -1133,7 +1137,7 @@ class Federation:
                 with tracer.span(
                     "train", round=rnd, participants=len(participants)
                 ):
-                    params, losses, steps, scanned, jax_rng = self._train_round(
+                    params, losses, steps, (scanned, lanes), jax_rng = self._train_round(
                         params, participants, rng, jax_rng, federation_spe
                     )
                 self.selection_policy.observe(participants, losses)
@@ -1163,6 +1167,7 @@ class Federation:
                     participants=len(participants),
                     local_steps=steps,
                     scanned_steps=scanned,
+                    lanes=lanes,
                 )
                 history.append(record)
                 with tracer.span("record", round=rnd):

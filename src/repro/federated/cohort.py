@@ -32,16 +32,21 @@ Staging (``staging=``) controls how a round's batches reach the device:
   bytes host->device.
 * ``"resident"`` — client train arrays are uploaded **once** per
   federation (``repro.data.device_cohort``, sharded over the mesh when one
-  is given) and a round stages only a compact ``(C, T, B)`` int32 index
-  plan drawn from the *same* RNG stream; the jitted round gathers each
-  step's batch from the resident arrays on device (``jnp.take`` along the
-  per-client sample axis), and the per-example mask is derived on device
-  as ``sample_idx < n_c``.  Per-round host->device traffic drops from
-  O(C*T*B*features) floats to O(C*T*B) int32s.  With ``prefetch`` (the
-  default) a ``StagingPipeline`` builds and uploads chunk k+1's plan on a
-  background thread while chunk k's donated step runs, and all host syncs
-  (per-chunk loss fetches) are deferred to the end of the round so XLA
-  dispatch stays ahead of the device.
+  is given) and a round stages only a compact int32 index plan drawn from
+  the *same* RNG stream, its real client-steps packed into a few vmap lanes
+  (``LanePlan``): clients train independently from the same global
+  parameters, so several share a lane one after another, and the round
+  scans ``W`` lanes of ``L`` slots instead of every client for the longest
+  client's step count.  ``W = min(C, W_fed)``, where ``W_fed`` is the lane
+  count first-fit decreasing needs for the attached federation, so one
+  compiled round serves every draw of a participant count.  Each step
+  gathers its batch as ``x[row, sample_idx]`` from the resident arrays on
+  device, and the per-example mask is derived on device.  Per-round
+  host->device traffic drops from O(C*T*B*features) floats to O(W*L*B)
+  int32s.  With ``prefetch`` (the default) a ``StagingPipeline`` builds and
+  uploads chunk k+1's plan on a background thread while chunk k's donated
+  step runs, and all host syncs (per-chunk loss fetches) are deferred to
+  the end of the round so XLA dispatch stays ahead of the device.
 
 Memory (the 189-client paper federation): the round step is jitted with
 ``donate_argnums`` so the cross-chunk accumulator is updated *in place*
@@ -57,11 +62,14 @@ live-buffer footprint is tracked per round in ``last_round_stats`` (see
 
 Multi-device: pass ``mesh`` (or the string ``"auto"`` to build a 1-D
 ``("data",)`` mesh over every local device) to shard the client axis with
-``shard_map``.  Cohorts that do not divide the axis size are padded with
-weight-0 dummy clients whose steps are all masked no-ops, and aggregation
-is a single cross-shard ``psum`` of the per-shard weighted sums — the only
-collective in the round.  ``cohort_chunk`` bounds peak memory by processing
-participants in chunks through the same donated accumulator.
+``shard_map``.  Rebuild staging pads cohorts that do not divide the axis
+size with weight-0 dummy clients whose steps are all masked no-ops;
+resident staging gives every shard the same number of lanes and trains
+each client on a lane of the shard that holds its rows, so no batch
+crosses shards.  Aggregation is a single cross-shard ``psum`` of the
+per-shard weighted sums — the only collective in the round.
+``cohort_chunk`` bounds peak memory by processing participants in chunks
+through the same donated accumulator.
 """
 
 from __future__ import annotations
@@ -78,9 +86,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.data.device_cohort import (
     DeviceCohort,
-    build_cohort_plan,
+    FederationLanes,
+    assign_lanes,
     build_device_cohort,
-    pad_cohort_plan,
+    build_lane_plan,
+    pack_federation,
 )
 from repro.data.pipeline import (
     ClientDataset,
@@ -160,12 +170,6 @@ class CohortTrainer:
     # live in an LRU pool and only each round's cohort is uploaded
     # (repro.data.device_cohort.ensure_resident).  None = bake everything.
     resident_budget_bytes: int | None = None
-    # Select a chunk whose client_rows are contiguous (and shard-aligned
-    # under a mesh) with a static lax.slice instead of a row gather —
-    # jnp.take with arbitrary indices forces GSPMD into a cross-shard
-    # gather; a static slice partitions natively.  Off only for parity
-    # diffing; numerically identical either way.
-    slice_fastpath: bool = True
     # Sample live-buffer peaks into last_round_stats (two process-wide
     # jax.live_arrays() walks per chunk).  Cheap, but disable on
     # latency-critical loops that never read the stats.
@@ -199,6 +203,7 @@ class CohortTrainer:
         self._data_mesh = mesh
         self._num_shards = int(mesh.shape["data"]) if mesh is not None else 1
         self._device_cohort: DeviceCohort | None = None
+        self._fed_lanes: FederationLanes | None = None
         self.dp = resolve_dp(self.dp)
 
         if self.dp is None:
@@ -251,32 +256,6 @@ class CohortTrainer:
             )
             return params, losses
 
-        def train_one_resident(params, x_c, y_c, idx_c, v_c, key_data, n_c):
-            """All local epochs for one client, gathering batches on device.
-
-            ``x_c``/``y_c`` are the client's resident ``(max_n + 1, ...)``
-            arrays; each scan step gathers its ``(B, ...)`` batch by index
-            and derives the example mask as ``idx < n_c`` (padding slots
-            point at the all-zero pad row, so the gathered batch is
-            bit-identical to the rebuilt schedule's)."""
-            opt_state = self.optimizer.init(params)
-
-            def step(carry, inp):
-                p, s, kd = carry
-                ib, valid = inp
-                batch = (
-                    jnp.take(x_c, ib, axis=0),
-                    jnp.take(y_c, ib, axis=0),
-                    (ib < n_c).astype(jnp.float32),
-                )
-                p, s, kd, loss = client_step(p, s, kd, batch, valid)
-                return (p, s, kd), loss
-
-            (params, _, _), losses = jax.lax.scan(
-                step, (params, opt_state, key_data), (idx_c, v_c)
-            )
-            return params, losses
-
         def train_block(params, x, y, mask, valid, key_data, weights, axis_name=None):
             """Train a block of clients and reduce to one weighted param sum.
 
@@ -289,30 +268,88 @@ class CohortTrainer:
             )(x, y, mask, valid, key_data)
             return weighted_sum_stacked(stacked, weights, axis_name=axis_name), losses
 
-        def train_block_resident(
-            params, x, y, idx, valid, key_data, weights, axis_name=None
+        def train_lanes(
+            params, x_all, y_all, rows, idx, valid, first, last, client, key_data,
+            weights, feature_shape, axis_name=None,
         ):
-            stacked, losses = jax.vmap(
-                lambda xc, yc, ic, vc, kd, nc: train_one_resident(
-                    params, xc, yc, ic, vc, kd, nc
-                )
-            )(x, y, idx, valid, key_data, weights)
-            return weighted_sum_stacked(stacked, weights, axis_name=axis_name), losses
+            """Train a block of packed lanes and reduce to one weighted param sum.
 
-        if mesh is not None:
-            sharded = functools.partial(
-                jax.shard_map,
-                mesh=mesh,
-                in_specs=(
-                    P(), P("data"), P("data"), P("data"), P("data"), P("data"), P("data"),
+            One scan over the plan's slots, vmapped over its lanes.  At a
+            ``first`` slot a lane restarts from the round's global params,
+            a fresh optimizer state and its client's key; at a ``last`` slot
+            it adds ``n_c * params`` to the FedAvg sum.  Each step gathers
+            its ``(B, ...)`` batch as ``x_all[row, idx]`` (each sample a
+            flat, lane-padded row, ``DeviceCohort``) and derives the example
+            mask as ``idx < pad`` (padding slots point at the all-zero pad
+            row, so the gathered batch is bit-identical to the rebuilt
+            schedule's).  Inside shard_map ``rows`` are local to the shard's
+            block of the resident arrays."""
+            opt0 = self.optimizer.init(params)
+            pad = x_all.shape[1] - 1
+            num_lanes = rows.shape[0]
+            size = int(np.prod(feature_shape))
+            bits = jnp.dtype(f"uint{8 * x_all.dtype.itemsize}")
+
+            def gather_x(row, ib):
+                # The features' only use is a default-precision matmul, so
+                # on a TPU XLA's bfloat16 propagation would otherwise move
+                # that matmul's input rounding back through the gather and
+                # convert the whole resident cohort on every call.  A round
+                # trip through the same bits behind a barrier stops the
+                # propagation at the step's batch; every number is the same.
+                xb = jax.lax.bitcast_convert_type(x_all[row, ib], bits)
+                xb = jax.lax.bitcast_convert_type(jax.lax.optimization_barrier(xb), x_all.dtype)
+                return xb[:, :size].reshape(*ib.shape, *feature_shape)
+
+            def lane_step(p, s, kd, row, ib, ok, start, kd0):
+                restart = lambda g, q: jnp.where(start, g, q)
+                p = jax.tree.map(restart, params, p)
+                s = jax.tree.map(restart, opt0, s)
+                kd = jnp.where(start, kd0, kd)
+                batch = (gather_x(row, ib), y_all[row, ib], (ib < pad).astype(jnp.float32))
+                return client_step(p, s, kd, batch, ok)
+
+            def slot(carry, inp):
+                p, s, kd, wsum = carry
+                row, ib, ok, start, end, c = inp
+                p, s, kd, loss = jax.vmap(lane_step)(p, s, kd, row, ib, ok, start, key_data[c])
+                done = weighted_sum_stacked(p, jnp.where(end, weights[c], 0.0))
+                return (p, s, kd, jax.tree.map(jnp.add, wsum, done)), loss
+
+            lanes_of = lambda tree: jax.tree.map(
+                lambda a: jnp.broadcast_to(a, (num_lanes, *a.shape)), tree
+            )
+            carry = (
+                lanes_of(params),
+                lanes_of(opt0),
+                jnp.zeros((num_lanes, *key_data.shape[1:]), key_data.dtype),
+                jax.tree.map(
+                    lambda a: jnp.zeros(a.shape, jnp.promote_types(a.dtype, jnp.float32)),
+                    params,
                 ),
-                out_specs=(P(), P("data")),
+            )
+            xs = tuple(jnp.swapaxes(a, 0, 1) for a in (rows, idx, valid, first, last, client))
+            (_, _, _, wsum), losses = jax.lax.scan(slot, carry, xs)
+            if axis_name is not None:
+                wsum = jax.tree.map(lambda a: jax.lax.psum(a, axis_name), wsum)
+            return wsum, jnp.swapaxes(losses, 0, 1)
+
+        data, rep = P("data"), P()
+
+        def on_mesh(block, *in_specs):
+            """``block`` per shard under shard_map, its sum psum'd; as is
+            without a mesh."""
+            if mesh is None:
+                return block
+            return jax.shard_map(
+                functools.partial(block, axis_name="data"),
+                mesh=mesh,
+                in_specs=in_specs,
+                out_specs=(rep, data),
                 check_vma=False,
             )
-            train_block = sharded(functools.partial(train_block, axis_name="data"))
-            train_block_resident = sharded(
-                functools.partial(train_block_resident, axis_name="data")
-            )
+
+        train_block = on_mesh(train_block, rep, data, data, data, data, data, data)
 
         def per_client_losses(losses, valid):
             # Per-client mean loss over the LAST epoch's real steps (matching
@@ -327,52 +364,31 @@ class CohortTrainer:
             acc = jax.tree.map(jnp.add, acc, wsum)
             return acc, per_client_losses(losses, valid)
 
-        def resident_block(params, acc, x_sel, y_sel, idx, valid, key_data, weights):
-            wsum, losses = train_block_resident(
-                params, x_sel, y_sel, idx, valid, key_data, weights
+        def lane_client_losses(losses, last_epoch, client, num_clients):
+            # The same per-client mean, gathered from the lanes: each
+            # client's last-epoch steps summed by its index in the round.
+            total = jax.ops.segment_sum(
+                jnp.where(last_epoch, losses, 0.0).ravel(), client.ravel(), num_clients
+            )
+            count = jax.ops.segment_sum(
+                last_epoch.ravel().astype(jnp.float32), client.ravel(), num_clients
+            )
+            return total / jnp.maximum(count, 1.0)
+
+        def packed_round(
+            params, acc, x_all, y_all, rows, idx, valid, first, last, client,
+            last_epoch, key_data, weights, feature_shape,
+        ):
+            block = on_mesh(
+                functools.partial(train_lanes, feature_shape=feature_shape),
+                rep, data, data, data, data, data, data, data, data, rep, rep,
+            )
+            wsum, losses = block(
+                params, x_all, y_all, rows, idx, valid, first, last, client,
+                key_data, weights,
             )
             acc = jax.tree.map(jnp.add, acc, wsum)
-            return acc, per_client_losses(losses, valid)
-
-        def cohort_round_resident(
-            params, acc, x_all, y_all, rows, idx, valid, key_data, weights
-        ):
-            # Select the chunk's client rows from the resident arrays on
-            # device (under a mesh this is a GSPMD gather from the sharded
-            # federation arrays, re-laid-out onto the cohort's data axis).
-            x_sel = jnp.take(x_all, rows, axis=0)
-            y_sel = jnp.take(y_all, rows, axis=0)
-            if mesh is not None:
-                sharding = NamedSharding(mesh, P("data"))
-                x_sel = jax.lax.with_sharding_constraint(x_sel, sharding)
-                y_sel = jax.lax.with_sharding_constraint(y_sel, sharding)
-            return resident_block(params, acc, x_sel, y_sel, idx, valid, key_data, weights)
-
-        def cohort_round_resident_full(params, acc, x_all, y_all, idx, valid, key_data, weights):
-            # Full-cohort fast path: the chunk IS the resident federation in
-            # row order (every all-participants round), so the row gather —
-            # a round-sized device copy — is skipped and the resident
-            # arrays feed the vmap directly.
-            return resident_block(params, acc, x_all, y_all, idx, valid, key_data, weights)
-
-        def cohort_round_resident_slice(
-            params, acc, x_all, y_all, idx, valid, key_data, weights, start
-        ):
-            # Static-slice fast path: this chunk's client rows are the
-            # contiguous run [start, start + C), so select them with a
-            # static lax.slice.  ``start`` is a static argnum (one compile
-            # per distinct chunk offset — a handful, reused every round):
-            # the partitioner sees literal slice bounds and keeps a
-            # shard-aligned chunk local instead of emitting the cross-shard
-            # gather that jnp.take's arbitrary indices force.
-            n = idx.shape[0]
-            x_sel = jax.lax.slice_in_dim(x_all, start, start + n, axis=0)
-            y_sel = jax.lax.slice_in_dim(y_all, start, start + n, axis=0)
-            if mesh is not None:
-                sharding = NamedSharding(mesh, P("data"))
-                x_sel = jax.lax.with_sharding_constraint(x_sel, sharding)
-                y_sel = jax.lax.with_sharding_constraint(y_sel, sharding)
-            return resident_block(params, acc, x_sel, y_sel, idx, valid, key_data, weights)
+            return acc, lane_client_losses(losses, last_epoch, client, weights.shape[0])
 
         # Donation layout: the accumulator (argnum 1) aliases in place
         # everywhere; on TPU/GPU the per-round staged buffers are donated
@@ -380,32 +396,18 @@ class CohortTrainer:
         # warns on and ignores donations it cannot alias to an output).
         # The resident cohort arrays (argnums 2-3 of the resident round)
         # are never donated — they outlive every round.
+        resident = self.staging == "resident"
         donate_argnums: tuple[int, ...] = ()
-        donate_staged = self.donate and jax.default_backend() != "cpu"
         if self.donate:
             donate_argnums = (1,)
-            if donate_staged:
-                donate_argnums += (
-                    (4, 5, 6, 7, 8) if self.staging == "resident" else (2, 3, 4, 5, 6, 7)
-                )
-        self._round = jax.jit(
-            cohort_round_resident if self.staging == "resident" else cohort_round,
-            donate_argnums=donate_argnums,
-        )
-        if self.staging == "resident":
-            # signature drops the rows arg: staged buffers sit at 4..7
-            full_donate: tuple[int, ...] = (1,) if self.donate else ()
-            if donate_staged:
-                full_donate += (4, 5, 6, 7)
-            self._round_full = jax.jit(
-                cohort_round_resident_full, donate_argnums=full_donate
+            if jax.default_backend() != "cpu":
+                donate_argnums += tuple(range(4, 13)) if resident else tuple(range(2, 8))
+        if resident:
+            self._round = jax.jit(
+                packed_round, donate_argnums=donate_argnums, static_argnames="feature_shape"
             )
-            # same staged layout as _round_full plus the static slice start
-            self._round_slice = jax.jit(
-                cohort_round_resident_slice,
-                donate_argnums=full_donate,
-                static_argnums=8,
-            )
+        else:
+            self._round = jax.jit(cohort_round, donate_argnums=donate_argnums)
 
     # ------------------------------------------------------------------
     # staging helpers
@@ -422,13 +424,23 @@ class CohortTrainer:
         too large for it, the cohort is an LRU pool and rounds upload only
         their sampled clients.
         """
-        self._device_cohort = build_device_cohort(
+        dc = build_device_cohort(
             clients,
             mesh=self._data_mesh,
             resident_budget_bytes=self.resident_budget_bytes,
             tracer=self.tracer,
         )
-        return self._device_cohort
+        # Pack the federation into lanes once: every round at its
+        # steps_per_epoch then places its participants in these lanes.
+        shard = np.zeros(len(clients), dtype=np.int64)
+        if not dc.is_pooled:
+            per_shard = dc.num_rows // self._num_shards
+            shard = np.asarray([dc.row_of(c) for c in clients]) // per_shard
+        self._fed_lanes = pack_federation(
+            clients, shard, self._num_shards, self.batch_size, self.local_epochs
+        )
+        self._device_cohort = dc
+        return dc
 
     def _ensure_device_cohort(self, clients: Sequence[ClientDataset]) -> DeviceCohort:
         dc = self._device_cohort
@@ -436,13 +448,20 @@ class CohortTrainer:
             return dc
         return self.attach_device_cohort(clients)
 
-    def _device_put_chunk(self, arrays: tuple) -> tuple:
-        """Stage one chunk's host arrays in a single pytree ``device_put``,
-        sharded over the mesh's data axis when one is present (every leaf
-        carries the client axis first)."""
+    def _device_put_chunk(self, arrays: tuple[tuple, tuple]) -> tuple:
+        """Stage one chunk's host arrays in a single pytree ``device_put``.
+
+        ``arrays`` is ``(sharded, replicated)``: under a mesh the first
+        group is sharded over its data axis (each carries the client or
+        lane axis first) and the second goes to every device.  Returns
+        both groups as one flat tuple."""
+        sharded, replicated = arrays
         if self._data_mesh is None:
-            return jax.device_put(arrays)
-        return jax.device_put(arrays, NamedSharding(self._data_mesh, P("data")))
+            return jax.device_put((*sharded, *replicated))
+        mesh = self._data_mesh
+        return jax.device_put(sharded, NamedSharding(mesh, P("data"))) + jax.device_put(
+            replicated, NamedSharding(mesh, P())
+        )
 
     @staticmethod
     def _stack_key_data(client_keys) -> np.ndarray | jax.Array:
@@ -502,7 +521,8 @@ class CohortTrainer:
         round's aggregated params, per-client mean local losses, and the
         number of *real* (unpadded) local steps executed;
         ``last_round_stats["scanned_steps"]`` counts the client-steps the
-        scan ran, padding clients and steps included.
+        scan ran, padding included: ``lanes * L`` under resident staging,
+        clients times their padded step axis under rebuild staging.
         """
         tracer = self.tracer
         with tracer.span("prepare", clients=len(clients)):
@@ -542,69 +562,48 @@ class CohortTrainer:
             peak["count"] = max(peak["count"], now["count"] - baseline["count"])
             peak["bytes"] = max(peak["bytes"], now["bytes"] - baseline["bytes"])
 
-        def _build_chunk(start: int) -> tuple[int, float, int, int, tuple, tuple]:
+        def _build_chunk(start: int) -> tuple[int, float, int, int, int, tuple]:
             """Build + upload one chunk's batch data.
 
             Returns (host bytes staged, chunk weight, real client count,
-            client-steps the round's scan runs, padding included,
-            (row-select path, slice start), device args for the round
-            step).  Consumes ``rng`` — must run strictly in chunk order
-            (the StagingPipeline's single ordered producer preserves this).
+            client-steps the round's scan runs, padding included, lanes,
+            device args for the round step).  Consumes ``rng`` — must run
+            strictly in chunk order (the StagingPipeline's single ordered
+            producer preserves this).
             """
             part = clients[start : start + chunk]
             if resident:
-                plan = build_cohort_plan(
-                    [c.n_train for c in part],
+                part_sizes = np.asarray([c.n_train for c in part], dtype=np.int64)
+                rows = np.asarray([dcohort.row_of(c) for c in part], dtype=np.int64)
+                per_shard = dcohort.num_rows // self._num_shards
+                shard = rows // per_shard
+                steps = -(-part_sizes // self.batch_size) * self.local_epochs
+                # The attached federation's packing, where this round runs at
+                # its step count; else the round packs its own participants.
+                fed = self._fed_lanes
+                packing = (None, None)
+                if fed is not None and fed.steps_per_epoch == spe:
+                    packing = ([fed.lane[c.client_id] for c in part], fed.width)
+                lane, width = assign_lanes(
+                    steps, shard, self._num_shards, spe * self.local_epochs, *packing
+                )
+                plan = build_lane_plan(
+                    part_sizes,
                     self.batch_size,
                     self.local_epochs,
                     rng,
+                    shard * width + lane,
+                    width * self._num_shards,
                     steps_per_epoch=spe,
-                    client_rows=[dcohort.row_of(c) for c in part],
+                    client_rows=rows % per_shard,
                     pad_index=dcohort.pad_index,
                 )
                 weight = float(plan.weights.sum())
-                plan = pad_cohort_plan(plan, self._num_shards, num_rows=dcohort.num_rows)
-                key_data = self._chunk_key_data(
-                    all_key_data, start, len(part), plan.num_clients
-                )
-                # Row-select path, best first: "full" — the chunk is the
-                # whole resident federation in row order (every
-                # all-participants round), no row select at all; "slice" —
-                # the rows are one contiguous (and, under a mesh,
-                # shard-aligned) run, a static lax.slice; "gather" — the
-                # general jnp.take.
-                full = plan.num_clients == dcohort.num_rows and np.array_equal(
-                    plan.client_rows[: len(part)], np.arange(len(part))
-                )
-                kind, r0 = "gather", 0
-                if full:
-                    kind = "full"
-                elif self.slice_fastpath:
-                    r0 = int(plan.client_rows[0])
-                    contiguous = np.array_equal(
-                        plan.client_rows,
-                        np.arange(
-                            r0, r0 + plan.num_clients, dtype=plan.client_rows.dtype
-                        ),
-                    )
-                    aligned = True
-                    if self._num_shards > 1:
-                        rps = dcohort.num_rows // self._num_shards
-                        aligned = (
-                            rps > 0
-                            and r0 % rps == 0
-                            and plan.num_clients % rps == 0
-                        )
-                    if contiguous and aligned:
-                        kind = "slice"
-                host: tuple = (plan.sample_idx, plan.step_valid, plan.weights)
-                to_stage: tuple = (plan.sample_idx, plan.step_valid, key_data, plan.weights)
-                if kind == "gather":
-                    host = (plan.client_rows, *host)
-                    to_stage = (plan.client_rows, *to_stage)
-                staged = self._device_put_chunk(to_stage)
-                path = (kind, r0)
-                scanned = int(plan.step_valid.size)  # (clients, steps), padding included
+                key_data = self._chunk_key_data(all_key_data, start, len(part), len(part))
+                host: tuple = (*plan.slot_arrays, plan.weights)
+                staged = self._device_put_chunk((plan.slot_arrays, (key_data, plan.weights)))
+                lanes = plan.num_lanes
+                scanned = int(plan.valid.size)  # (lanes, slots), empty slots included
             else:
                 sched = build_cohort_schedule(
                     [c.train for c in part],
@@ -620,18 +619,18 @@ class CohortTrainer:
                 key_data = self._chunk_key_data(
                     all_key_data, start, len(part), sched.num_clients
                 )
-                path = ("gather", 0)
+                lanes = sched.num_clients
                 scanned = int(sched.step_valid.size)
                 host = (sched.x, sched.y, sched.mask, sched.step_valid, sched.weights)
                 staged = self._device_put_chunk(
-                    (sched.x, sched.y, sched.mask, sched.step_valid, key_data, sched.weights)
+                    ((sched.x, sched.y, sched.mask, sched.step_valid, key_data, sched.weights), ())
                 )
             nbytes = sum(a.nbytes for a in host)
             if isinstance(key_data, np.ndarray):
                 nbytes += key_data.nbytes
-            return nbytes, weight, len(part), scanned, path, staged
+            return nbytes, weight, len(part), scanned, lanes, staged
 
-        def stage_chunk(start: int) -> tuple[int, float, int, int, tuple, tuple]:
+        def stage_chunk(start: int) -> tuple[int, float, int, int, int, tuple]:
             # The span lands on whichever thread stages — inline here, or
             # the StagingPipeline's producer during prefetch.
             with tracer.span("stage", track="staging", chunk=int(start)):
@@ -640,6 +639,7 @@ class CohortTrainer:
         total_weight = 0.0
         bytes_staged = 0
         scanned_steps = 0
+        total_lanes = 0
         num_chunks = 0
         # Per-chunk device loss arrays; fetched once after the whole round
         # is dispatched so chunk k+1 never blocks on chunk k's readback.
@@ -656,14 +656,16 @@ class CohortTrainer:
         # iteration's first sample() so the plain (non-donated) path's
         # documented two-chunk window is actually observed in the stats.
         held: list[tuple] = []
-        slice_chunks = 0
+        resident_arrays = (dcohort.x, dcohort.y) if resident else ()
+        features = {"feature_shape": dcohort.feature_shape} if resident else {}
         try:
-            for start, (nbytes, weight, count, scanned, path, args) in zip(
+            for start, (nbytes, weight, count, scanned, lanes, args) in zip(
                 starts, staged_chunks
             ):
                 total_weight += weight
                 bytes_staged += nbytes
                 scanned_steps += scanned
+                total_lanes += lanes
                 # Sampled before the previous chunk's buffers (still
                 # referenced by ``held`` on the non-donated path) are
                 # released: the plain rebuild path holds two chunks of
@@ -671,23 +673,7 @@ class CohortTrainer:
                 sample()
                 held.clear()
                 with tracer.span("dispatch", chunk=int(start)):
-                    if resident:
-                        kind, r0 = path
-                        if kind == "full":
-                            acc, losses = self._round_full(
-                                params, acc, dcohort.x, dcohort.y, *args
-                            )
-                        elif kind == "slice":
-                            slice_chunks += 1
-                            acc, losses = self._round_slice(
-                                params, acc, dcohort.x, dcohort.y, *args, r0
-                            )
-                        else:
-                            acc, losses = self._round(
-                                params, acc, dcohort.x, dcohort.y, *args
-                            )
-                    else:
-                        acc, losses = self._round(params, acc, *args)
+                    acc, losses = self._round(params, acc, *resident_arrays, *args, **features)
                     if self.donate:
                         # Realize the donation of the staged chunk: the step
                         # consumed it, free the device copies now instead of
@@ -728,7 +714,7 @@ class CohortTrainer:
                 "plans_prefetched": pipeline.prefetched if pipeline is not None else 0,
                 "peak_live_buffers": peak["count"],
                 "peak_live_bytes": peak["bytes"],
-                "slice_chunks": slice_chunks,
+                "lanes": total_lanes,
                 "scanned_steps": scanned_steps,
                 "pool": pooled,
                 "pool_rows": dcohort.pool_rows if pooled else 0,

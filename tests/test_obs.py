@@ -501,8 +501,9 @@ class TestTracedFederation:
         spe = max(math.ceil(c.n_train / 4) for c in clients)
         for span, record in zip(rounds, out.history):
             assert span.args["local_steps"] == record.local_steps
-            # every client scans the federation's largest step axis
-            assert span.args["scanned_steps"] == len(clients) * 2 * spe
+            # every lane scans the federation's largest step axis
+            assert span.args["scanned_steps"] == span.args["lanes"] * 2 * spe
+            assert 1 <= span.args["lanes"] <= len(clients)
             assert span.args["scanned_steps"] >= span.args["local_steps"]
         counters = out.metrics["counters"]
         assert counters["train.scanned_steps"] == sum(s.args["scanned_steps"] for s in rounds)
@@ -724,7 +725,8 @@ class TestStagingCounters:
         # A pool budget below the cohort footprint forces uploads and LRU
         # evictions as the seeded per-round selections churn the residents.
         max_n = max(c.n_train for c in clients)
-        row_bytes = (max_n + 1) * (SEQ_LEN * FEAT * 4 + 4)
+        # a stay's SEQ_LEN x FEAT features are one row padded to 128 lanes
+        row_bytes = (max_n + 1) * (128 * 4 + 4)
         rounds = 4
         fed = Federation(
             FederationConfig(

@@ -1,17 +1,14 @@
-"""Population-scale staging: LRU resident pools, the static-slice fast
-path, and the staging-pipeline error contract.
+"""Population-scale staging: LRU resident pools and the staging-pipeline
+error contract.
 
 The contract of ``resident_budget_bytes``: a federation whose baked cohort
 exceeds the budget trains out of a bounded LRU pool of resident rows —
 rows upload lazily per round via ``ensure_resident`` (run once per round,
 before any plan is staged, so prefetch never races an eviction) — and the
 aggregated params match the fully resident path within the engine parity
-suite's 1e-5.  The slice fast path is the same kind of claim: when a
-chunk's resident rows form one contiguous (shard-aligned) run, selecting
-them with a static ``lax.slice`` instead of ``jnp.take`` must be a pure
-routing change, bit-identical params.  And ``StagingPipeline.close`` must
-never swallow a producer exception the consumer didn't collect, nor
-silently abandon a stuck producer thread.
+suite's 1e-5.  And ``StagingPipeline.close`` must never swallow a producer
+exception the consumer didn't collect, nor silently abandon a stuck
+producer thread.
 """
 
 import logging
@@ -22,11 +19,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.data.device_cohort import (
-    build_cohort_plan,
-    build_device_cohort,
-    pad_cohort_plan,
-)
+from repro.data.device_cohort import build_device_cohort
 from repro.data.pipeline import ArrayDataset, ClientDataset
 from repro.federated.cohort import CohortTrainer, chain_split_keys
 from repro.federated.staging import StagingPipeline
@@ -39,9 +32,10 @@ SEQ_LEN, FEAT = 4, 6
 
 def row_bytes_of(clients) -> int:
     """One padded client row in the device cohort these clients would bake:
-    ``(max_n + 1)`` samples of x plus y."""
+    ``(max_n + 1)`` samples of x (each stay's SEQ_LEN * FEAT features one row
+    padded to 128 lanes) plus y."""
     max_n = max(c.n_train for c in clients)
-    return (max_n + 1) * SEQ_LEN * FEAT * 4 + (max_n + 1) * 4
+    return (max_n + 1) * 128 * 4 + (max_n + 1) * 4
 
 
 def make_clients(count: int, rng: np.random.Generator, lo: int = 2, hi: int = 9):
@@ -130,8 +124,10 @@ def test_lru_evicts_oldest_untouched_and_reuploads_correctly(model):
     # the evicted client's row was handed to c4 with its data re-staged
     c4 = clients[4]
     row = np.asarray(dc.x[dc.row_of(c4)])
-    np.testing.assert_array_equal(row[: c4.n_train], c4.train.x)
+    features = row[: c4.n_train, : SEQ_LEN * FEAT].reshape(c4.n_train, SEQ_LEN, FEAT)
+    np.testing.assert_array_equal(features, c4.train.x)
     np.testing.assert_array_equal(row[c4.n_train :], 0.0)
+    np.testing.assert_array_equal(row[:, SEQ_LEN * FEAT :], 0.0)
     np.testing.assert_array_equal(
         np.asarray(dc.y[dc.row_of(c4)])[: c4.n_train], c4.train.y
     )
@@ -177,80 +173,12 @@ def test_pool_refuses_mesh():
         )
 
 
-# --------------------------------------------------------------------------
-# the static-slice fast path is routing, not math
-# --------------------------------------------------------------------------
-
 def run_full_round(trainer, params, clients):
     _, subs = chain_split_keys(jax.random.key(5), len(clients))
     params, _, _ = trainer.train_cohort(
         params, clients, np.random.default_rng(1), subs, steps_per_epoch=2
     )
     return jax.block_until_ready(params)
-
-
-def test_slice_fastpath_bitwise_vs_gather(model):
-    """All-participant chunks are contiguous resident-row runs: the slice
-    path must take them (3 chunks of 8) and produce bit-identical params to
-    the forced gather."""
-    loss_fn, params0 = model
-    clients = make_clients(24, np.random.default_rng(8))
-    results = {}
-    for fast in (True, False):
-        trainer = make_trainer(loss_fn, cohort_chunk=8, slice_fastpath=fast)
-        results[fast] = run_full_round(trainer, params0, clients)
-        assert trainer.last_round_stats["slice_chunks"] == (3 if fast else 0)
-    for la, lb in zip(jax.tree.leaves(results[True]), jax.tree.leaves(results[False])):
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-
-
-def test_noncontiguous_cohort_falls_back_to_gather(model):
-    """A strided subset has no contiguous row run — the fast path must
-    decline (slice_chunks == 0), not slice the wrong rows."""
-    loss_fn, params0 = model
-    clients = make_clients(16, np.random.default_rng(9))
-    trainer = make_trainer(loss_fn, cohort_chunk=4)
-    run_full_round(trainer, params0, clients)  # attach (rows = client order)
-    subset = clients[::2]
-    _, subs = chain_split_keys(jax.random.key(6), len(subset))
-    trainer.train_cohort(
-        params0, subset, np.random.default_rng(2), subs, steps_per_epoch=2
-    )
-    assert trainer.last_round_stats["slice_chunks"] == 0
-
-
-@pytest.mark.skipif(jax.device_count() < 2, reason="needs >1 device")
-def test_slice_fastpath_bitwise_under_mesh(model):
-    """Under the data mesh, shard-aligned contiguous chunks go through the
-    slice path (this is what re-enabled chunking in the mesh benchmarks)
-    and still match the forced gather bit for bit."""
-    loss_fn, params0 = model
-    mesh = make_data_mesh()
-    clients = make_clients(24, np.random.default_rng(11))
-    results = {}
-    for fast in (True, False):
-        trainer = make_trainer(
-            loss_fn, cohort_chunk=12, mesh=mesh, slice_fastpath=fast
-        )
-        results[fast] = run_full_round(trainer, params0, clients)
-        assert trainer.last_round_stats["slice_chunks"] == (2 if fast else 0)
-    for la, lb in zip(jax.tree.leaves(results[True]), jax.tree.leaves(results[False])):
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-
-
-def test_pad_cohort_plan_keeps_contiguity_when_rows_allow():
-    """Dummy clients borrow the continuation rows (keeping the slice path
-    alive) when the device cohort has them, and fall back to row 0 when it
-    does not — either way every dummy slot gathers the all-zero pad row."""
-    plan = build_cohort_plan(
-        [3, 5, 4], 2, 1, np.random.default_rng(0), client_rows=[4, 5, 6]
-    )
-    padded = pad_cohort_plan(plan, 4, num_rows=8)
-    np.testing.assert_array_equal(padded.client_rows, [4, 5, 6, 7])
-    assert (padded.sample_idx[3] == plan.pad_index).all()
-    assert not padded.step_valid[3].any() and padded.weights[3] == 0.0
-    cramped = pad_cohort_plan(plan, 4, num_rows=7)  # no room after row 6
-    np.testing.assert_array_equal(cramped.client_rows, [4, 5, 6, 0])
 
 
 # --------------------------------------------------------------------------

@@ -1,8 +1,9 @@
 """Device-resident staging vs the rebuild path vs the sequential oracle.
 
 The contract of ``staging="resident"``: client train arrays are uploaded
-once per federation, every round stages only a ``(C, T, B)`` int32 index
-plan drawn from the *same* numpy RNG stream as ``build_cohort_schedule``,
+once per federation, every round stages only an int32 index plan packed
+into a few lanes (``LanePlan``) drawn from the *same* numpy RNG stream as
+``build_cohort_schedule``,
 and the on-device batch gather reproduces the rebuilt schedule's batches
 **bitwise** — so aggregated params match the PR-2 rebuild path and the
 sequential oracle within the same 1e-5 the engine parity suite uses,
@@ -21,9 +22,9 @@ import numpy as np
 import pytest
 
 from repro.data.device_cohort import (
-    build_cohort_plan,
+    assign_lanes,
     build_device_cohort,
-    pad_cohort_plan,
+    build_lane_plan,
 )
 from repro.data.pipeline import (
     ArrayDataset,
@@ -73,11 +74,20 @@ def assert_params_close(a, b, atol=1e-5):
 # the index plan is the schedule, bit for bit
 # --------------------------------------------------------------------------
 
+def packed_plan(sizes, batch, epochs, rng, **kwargs):
+    """A lane plan with the cohort packed by first-fit decreasing."""
+    steps = [-(-n // batch) * epochs for n in sizes]
+    spe = max(-(-n // batch) for n in sizes)
+    lanes, width = assign_lanes(steps, np.zeros(len(sizes), int), 1, spe * epochs)
+    return build_lane_plan(sizes, batch, epochs, rng, lanes, width, **kwargs)
+
+
 def test_plan_gathers_schedule_bitwise():
-    """Gathering the resident arrays through the plan reproduces the
-    rebuilt schedule's x/y/mask arrays exactly — the parity foundation."""
+    """Gathering the resident arrays through the packed plan reproduces
+    each client's real steps of the rebuilt schedule exactly — x, y and
+    mask, in order — the parity foundation."""
     rng = np.random.default_rng(3)
-    sizes = (5, 9, 12)
+    sizes = (5, 9, 12, 3)
     data = [
         ArrayDataset(
             rng.normal(size=(n, 2, 3)).astype(np.float32),
@@ -87,19 +97,22 @@ def test_plan_gathers_schedule_bitwise():
     ]
     batch, epochs = 4, 2
     sched = build_cohort_schedule(data, batch, epochs, np.random.default_rng(7))
-    plan = build_cohort_plan(sizes, batch, epochs, np.random.default_rng(7))
+    plan = packed_plan(sizes, batch, epochs, np.random.default_rng(7))
     assert plan.pad_index == max(sizes)
-    np.testing.assert_array_equal(plan.step_valid, sched.step_valid)
+    assert plan.num_lanes < len(sizes)  # the cohort really was packed
     np.testing.assert_array_equal(plan.weights, sched.weights)
     # emulate the on-device gather on host: pad each client to pad_index+1
     for c, d in enumerate(data):
         xp = np.zeros((plan.pad_index + 1, 2, 3), np.float32)
         yp = np.zeros(plan.pad_index + 1, np.float32)
         xp[: sizes[c]], yp[: sizes[c]] = d.x, d.y
-        np.testing.assert_array_equal(xp[plan.sample_idx[c]], sched.x[c])
-        np.testing.assert_array_equal(yp[plan.sample_idx[c]], sched.y[c])
-        mask = (plan.sample_idx[c] < sizes[c]).astype(np.float32)
-        np.testing.assert_array_equal(mask, sched.mask[c])
+        mine = plan.valid & (plan.client == c)
+        idx = plan.sample_idx[mine]  # row-major: lane order, then slot order
+        real = sched.step_valid[c]
+        np.testing.assert_array_equal(xp[idx], sched.x[c][real])
+        np.testing.assert_array_equal(yp[idx], sched.y[c][real])
+        mask = (idx < plan.pad_index).astype(np.float32)
+        np.testing.assert_array_equal(mask, sched.mask[c][real])
 
 
 def test_plan_consumes_rng_like_schedule():
@@ -116,28 +129,13 @@ def test_plan_consumes_rng_like_schedule():
     ]
     r_sched, r_plan = np.random.default_rng(5), np.random.default_rng(5)
     build_cohort_schedule(data, 8, 3, r_sched)
-    build_cohort_plan(sizes, 8, 3, r_plan)
+    packed_plan(sizes, 8, 3, r_plan)
     assert r_sched.bit_generator.state == r_plan.bit_generator.state
-
-
-def test_pad_cohort_plan():
-    plan = build_cohort_plan([5, 9, 12], 4, 1, np.random.default_rng(0))
-    padded = pad_cohort_plan(plan, 4)
-    assert padded.num_clients == 4
-    assert pad_cohort_plan(plan, 1) is plan
-    assert pad_cohort_plan(plan, 3) is plan  # already divides
-    # dummy client: zero weight, no valid steps, every slot on the pad row
-    assert padded.weights[-1] == 0.0
-    assert not padded.step_valid[-1].any()
-    assert (padded.sample_idx[-1] == plan.pad_index).all()
-    # real clients untouched
-    np.testing.assert_array_equal(padded.sample_idx[:3], plan.sample_idx)
-    np.testing.assert_array_equal(padded.client_rows[:3], plan.client_rows)
 
 
 def test_plan_rejects_small_pad_index():
     with pytest.raises(ValueError, match="pad_index"):
-        build_cohort_plan([5, 9], 4, 1, np.random.default_rng(0), pad_index=7)
+        packed_plan([5, 9], 4, 1, np.random.default_rng(0), pad_index=7)
 
 
 def test_device_cohort_layout():
@@ -145,17 +143,23 @@ def test_device_cohort_layout():
     clients = make_clients(3, rng, lo=3, hi=8)
     dc = build_device_cohort(clients)
     max_n = max(c.n_train for c in clients)
-    assert dc.x.shape == (3, max_n + 1, SEQ_LEN, FEAT)
+    # each stay's SEQ_LEN x FEAT features are one row padded to 128 lanes
+    assert dc.x.shape == (3, max_n + 1, 128)
+    assert dc.feature_shape == (SEQ_LEN, FEAT)
     assert dc.y.shape == (3, max_n + 1)
     assert dc.pad_index == max_n
     assert dc.nbytes == dc.x.nbytes + dc.y.nbytes
     for c in clients:
         r = dc.row_of(c)
         assert dc.owns(c)
-        np.testing.assert_array_equal(np.asarray(dc.x)[r, : c.n_train], c.train.x)
+        x = np.asarray(dc.x)[r]
+        np.testing.assert_array_equal(
+            x[: c.n_train, : SEQ_LEN * FEAT].reshape(c.n_train, SEQ_LEN, FEAT), c.train.x
+        )
         np.testing.assert_array_equal(np.asarray(dc.y)[r, : c.n_train], c.train.y)
-        # rows past n_train (the pad row included) are zero
-        assert np.asarray(dc.x)[r, c.n_train :].sum() == 0.0
+        # rows past n_train (the pad row included) and the lane padding are zero
+        assert x[c.n_train :].sum() == 0.0
+        assert x[:, SEQ_LEN * FEAT :].sum() == 0.0
     stranger = make_clients(1, rng)[0]
     assert not dc.owns(stranger)
     with pytest.raises(KeyError):

@@ -30,8 +30,11 @@ GRU_B, GRU_T, GRU_N = 128, 24, 32
 CLIENTS = 8
 SSD = dict(b=1, nc=2, l=256, h=24, p=64, n=128)
 # The paper's federation: 189 hospitals, the largest with 8,336 train stays
-# (66 steps of 128 per epoch), 24 hourly steps of 38 features.
-ROUND = dict(clients=189, rows=8337, steps=66, hours=24, features=38)
+# (66 steps of 128 per epoch, 264 over 4 local epochs), 24 hourly steps of
+# 38 features; first-fit decreasing packs the 189 into 9 lanes of 264 slots.
+# The resident cohort keeps each stay's 912 features as one row padded to
+# 1024 lanes.
+ROUND = dict(clients=189, rows=8337, lanes=9, slots=264, hours=24, features=38, width=1024)
 
 
 @pytest.fixture(scope="module")
@@ -121,9 +124,10 @@ def test_ssd_backward_compiles_for_v5e(one_chip):
 
 
 def test_paper_round_with_pallas_compiles_for_v5e(one_chip, monkeypatch):
-    """The resident full-cohort round the engine runs every all-clients
-    round, with ``use_pallas=True`` and the backend steered to TPU so the
-    forward and backward kernels are compiled, not interpreted."""
+    """The resident round program (the one the engine runs every resident
+    round) at the all-clients round's packed shape, with ``use_pallas=True``
+    and the backend steered to TPU so the forward and backward kernels are
+    compiled, not interpreted."""
     from repro.federated.cohort import CohortTrainer
     from repro.models.gru import GRUConfig, init_gru, make_loss_fn
     from repro.optim.adamw import AdamW
@@ -139,16 +143,21 @@ def test_paper_round_with_pallas_compiles_for_v5e(one_chip, monkeypatch):
     )
     params = jax.eval_shape(lambda: init_gru(jax.random.key(0), cfg))
     p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
-    c, rows, steps = ROUND["clients"], ROUND["rows"], ROUND["steps"]
+    c, rows, lanes, slots = (ROUND[k] for k in ("clients", "rows", "lanes", "slots"))
     args = (
-        shapes(one_chip, (c, rows, ROUND["hours"], ROUND["features"]), (c, rows))
-        + shapes(one_chip, (c, steps, GRU_B), dtype=jnp.int32)
-        + shapes(one_chip, (c, steps), dtype=jnp.bool_)
+        shapes(one_chip, (c, rows, ROUND["width"]), (c, rows))
+        + shapes(one_chip, (lanes, slots), dtype=jnp.int32)  # rows
+        + shapes(one_chip, (lanes, slots, GRU_B), dtype=jnp.int32)  # sample_idx
+        + shapes(one_chip, (lanes, slots), (lanes, slots), (lanes, slots), dtype=jnp.bool_)
+        + shapes(one_chip, (lanes, slots), dtype=jnp.int32)  # client
+        + shapes(one_chip, (lanes, slots), dtype=jnp.bool_)  # last_epoch
         + shapes(one_chip, (c, 2), dtype=jnp.uint32)
         + shapes(one_chip, (c,))
     )
     with cache_off():
-        compiled = trainer._round_full.lower(p, p, *args).compile()
+        compiled = trainer._round.lower(
+            p, p, *args, feature_shape=(ROUND["hours"], ROUND["features"])
+        ).compile()
     text = compiled.as_text()
     # forward and backward kernel of each of the 2 layers
     assert text.count("tpu_custom_call") >= 4
