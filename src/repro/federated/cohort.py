@@ -22,7 +22,8 @@ Parity with the sequential oracle is exact by construction:
   the key), so per-step dropout keys match the sequential path;
 * aggregation is the same FedAvg weighted mean: per-chunk unnormalized
   weighted sums accumulated into a running pytree, normalized once at the
-  end of the round.
+  end of the round.  The sum starts inside the round's first program and
+  the last one divides it, so a one-chunk round is one program.
 
 Staging (``staging=``) controls how a round's batches reach the device:
 
@@ -359,10 +360,20 @@ class CohortTrainer:
             count = jnp.maximum(last_valid.sum(axis=1), 1)
             return jnp.where(last_valid, last, 0.0).sum(axis=1) / count
 
-        def cohort_round(params, acc, x, y, mask, valid, key_data, weights):
+        def fold(params, acc, wsum, total):
+            """The running FedAvg sum after this chunk, or at the round's last
+            chunk (``total``: the round's weight) its mean in the params'
+            dtype.  A round's first chunk passes no ``acc``: its sum starts
+            here."""
+            if acc is not None:
+                wsum = jax.tree.map(jnp.add, acc, wsum)
+            if total is None:
+                return wsum
+            return jax.tree.map(lambda s, p: (s / total).astype(p.dtype), wsum, params)
+
+        def cohort_round(params, acc, x, y, mask, valid, key_data, weights, total=None):
             wsum, losses = train_block(params, x, y, mask, valid, key_data, weights)
-            acc = jax.tree.map(jnp.add, acc, wsum)
-            return acc, per_client_losses(losses, valid)
+            return fold(params, acc, wsum, total), per_client_losses(losses, valid)
 
         def lane_client_losses(losses, last_epoch, client, num_clients):
             # The same per-client mean, gathered from the lanes: each
@@ -377,7 +388,7 @@ class CohortTrainer:
 
         def packed_round(
             params, acc, x_all, y_all, rows, idx, valid, first, last, client,
-            last_epoch, key_data, weights, feature_shape,
+            last_epoch, key_data, weights, feature_shape, total=None,
         ):
             block = on_mesh(
                 functools.partial(train_lanes, feature_shape=feature_shape),
@@ -387,15 +398,20 @@ class CohortTrainer:
                 params, x_all, y_all, rows, idx, valid, first, last, client,
                 key_data, weights,
             )
-            acc = jax.tree.map(jnp.add, acc, wsum)
-            return acc, lane_client_losses(losses, last_epoch, client, weights.shape[0])
+            return (
+                fold(params, acc, wsum, total),
+                lane_client_losses(losses, last_epoch, client, weights.shape[0]),
+            )
 
-        # Donation layout: the accumulator (argnum 1) aliases in place
-        # everywhere; on TPU/GPU the per-round staged buffers are donated
-        # too so XLA reuses their memory for round temporaries (XLA:CPU
-        # warns on and ignores donations it cannot alias to an output).
-        # The resident cohort arrays (argnums 2-3 of the resident round)
-        # are never donated — they outlive every round.
+        # Donation layout: the running sum (argnum 1; None in a round's
+        # first program) aliases in place everywhere; on TPU/GPU the
+        # per-round staged buffers are donated too so XLA reuses their
+        # memory for round temporaries (XLA:CPU warns on and ignores
+        # donations it cannot alias to an output).  The resident cohort
+        # arrays (argnums 2-3 of the resident round) are never donated —
+        # they outlive every round — nor are the params, which the caller
+        # keeps.  ``total`` (keyword) is the round's weight, given to its
+        # last program only.
         resident = self.staging == "resident"
         donate_argnums: tuple[int, ...] = ()
         if self.donate:
@@ -518,11 +534,13 @@ class CohortTrainer:
         federation-wide ``steps_per_epoch`` to pin the schedule's step axis
         across rounds — otherwise it tracks this cohort's largest client and
         a different participant mix can retrigger compilation.  Returns the
-        round's aggregated params, per-client mean local losses, and the
-        number of *real* (unpadded) local steps executed;
-        ``last_round_stats["scanned_steps"]`` counts the client-steps the
-        scan ran, padding included: ``lanes * L`` under resident staging,
-        clients times their padded step axis under rebuild staging.
+        round's aggregated params (the last round program's own outputs),
+        per-client mean local losses, and the number of *real* (unpadded)
+        local steps executed; ``last_round_stats["scanned_steps"]`` counts
+        the client-steps the scan ran, padding included: ``lanes * L`` under
+        resident staging, clients times their padded step axis under rebuild
+        staging.  ``last_round_stats["programs"]`` counts the round programs
+        dispatched, one per chunk.
         """
         tracer = self.tracer
         with tracer.span("prepare", clients=len(clients)):
@@ -551,9 +569,6 @@ class CohortTrainer:
 
             baseline = live_buffer_stats() if self.track_stats else {"count": 0, "bytes": 0}
             peak = {"count": 0, "bytes": 0}
-            acc = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.promote_types(p.dtype, jnp.float32)), params
-            )
 
         def sample() -> None:
             if not self.track_stats:
@@ -636,6 +651,9 @@ class CohortTrainer:
             with tracer.span("stage", track="staging", chunk=int(start)):
                 return _build_chunk(start)
 
+        # The running FedAvg sum between chunks: the first chunk's program
+        # starts it, the last one's returns the round's params.
+        acc = None
         total_weight = 0.0
         bytes_staged = 0
         scanned_steps = 0
@@ -672,8 +690,13 @@ class CohortTrainer:
                 # schedule here, the donated path one.
                 sample()
                 held.clear()
+                # The chunks are staged in order, so at the last one the
+                # round's whole weight is known.
+                total = np.float32(total_weight) if start == starts[-1] else None
                 with tracer.span("dispatch", chunk=int(start)):
-                    acc, losses = self._round(params, acc, *resident_arrays, *args, **features)
+                    acc, losses = self._round(
+                        params, acc, *resident_arrays, *args, total=total, **features
+                    )
                     if self.donate:
                         # Realize the donation of the staged chunk: the step
                         # consumed it, free the device copies now instead of
@@ -699,12 +722,10 @@ class CohortTrainer:
                 per_losses[start : start + count] = np.asarray(losses)[:count]
 
         with tracer.span("finalize"):
-            new_params = jax.tree.map(
-                lambda t, ref: (t / total_weight).astype(ref.dtype), acc, params
-            )
             pooled = resident and dcohort.is_pooled
             self.last_round_stats = {
                 "chunks": num_chunks,
+                "programs": num_chunks,
                 "shards": self._num_shards,
                 "donated": self.donate,
                 "staging": self.staging,
@@ -726,7 +747,7 @@ class CohortTrainer:
             real_steps = sum(
                 local_round_steps(n, self.batch_size, self.local_epochs) for n in sizes
             )
-        return new_params, per_losses, real_steps
+        return acc, per_losses, real_steps
 
     def steps_per_round(self, client: ClientDataset) -> int:
         return local_round_steps(client.n_train, self.batch_size, self.local_epochs)

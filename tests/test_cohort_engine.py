@@ -7,8 +7,10 @@ identical FedAvg weighting — the dummy padding steps are exact no-ops).
 """
 
 import dataclasses
+import math
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -21,12 +23,15 @@ from repro.data.pipeline import (
     cohort_steps_per_epoch,
 )
 from repro.data.synth_eicu import CohortConfig, generate_cohort
+from repro.federated import Federation, FederationConfig
 from repro.federated.cohort import CohortTrainer
 from repro.federated.fedavg import aggregate, aggregate_stacked, tree_allclose
 from repro.federated.server import FederatedConfig, FederatedServer
 from repro.launch.mesh import make_host_mesh
 from repro.models.gru import GRUConfig, init_gru, make_loss_fn
+from repro.obs.trace import Tracer
 from repro.optim.adamw import AdamW
+from repro.privacy.dp import DPConfig
 
 SEQ_LEN, FEAT = 6, 38  # short stays keep the GRU scan cheap
 
@@ -249,3 +254,130 @@ def test_single_compilation_across_rounds(model):
 def test_engine_default_is_vectorized():
     assert FederatedConfig().engine == "vectorized"
     assert dataclasses.replace(FederatedConfig(), engine="sequential").engine == "sequential"
+
+
+# --------------------------------------------------------------------------
+# the FedAvg fold: a round's programs start the sum and divide it
+# --------------------------------------------------------------------------
+
+# Six clients, so chunks of 1 and of 3 split them evenly.
+FOLD_SIZES = (9, 23, 4, 17, 30, 12)
+STAGINGS = ("resident", "rebuild")
+DPS = {"plain": None, "dp": DPConfig(clip_norm=1.0, noise_multiplier=1.1)}
+CHUNKS = (None, 1, 3)
+
+
+class RoundSpy:
+    """Wraps a trainer's round program and records each call's outputs and,
+    at a round's last program, the weighted sum that program divides: the
+    same program on copies of its inputs, with no ``total``."""
+
+    def __init__(self, trainer: CohortTrainer):
+        self.inner, self.outputs, self.sums = trainer._round, [], []
+        trainer._round = self
+
+    def __call__(self, params, acc, *args, total=None, **kwargs):
+        if total is not None:
+            acc_copy, args_copy = jax.tree.map(jnp.copy, (acc, args))
+            wsum, _ = self.inner(params, acc_copy, *args_copy, **kwargs)
+            self.sums.append((wsum, total))
+        out = self.inner(params, acc, *args, total=total, **kwargs)
+        self.outputs.append(out)
+        return out
+
+
+@pytest.fixture(scope="module")
+def small_model():
+    cfg = GRUConfig(input_dim=FEAT, hidden_dim=4, num_layers=1)
+    return make_loss_fn(cfg), init_gru(jax.random.key(1), cfg)
+
+
+@pytest.fixture(scope="module")
+def fold_round(small_model):
+    """One round over the FOLD_SIZES clients by (staging, dp, chunk), each
+    run once: the returned params, per-client losses, stats and spy."""
+    loss_fn, params0 = small_model
+    clients = [make_client(i, n, np.random.default_rng(i)) for i, n in enumerate(FOLD_SIZES)]
+    runs = {}
+
+    def run(staging, dp, chunk):
+        if (staging, dp, chunk) not in runs:
+            trainer = CohortTrainer(
+                loss_fn, AdamW(learning_rate=5e-3, weight_decay=5e-3), batch_size=8,
+                local_epochs=2, cohort_chunk=chunk, staging=staging, dp=DPS[dp],
+            )
+            spy = RoundSpy(trainer)
+            keys = list(jax.random.split(jax.random.key(4), len(clients)))
+            params, losses, _ = trainer.train_cohort(
+                params0, clients, np.random.default_rng(5), keys
+            )
+            runs[staging, dp, chunk] = params, losses, trainer.last_round_stats, spy
+        return runs[staging, dp, chunk]
+
+    return run
+
+
+fold_grid = pytest.mark.parametrize(
+    "staging,dp,chunk",
+    [(s, d, c) for s in STAGINGS for d in DPS for c in CHUNKS],
+)
+
+
+@fold_grid
+def test_round_params_are_the_mean_of_the_rounds_sum(
+    fold_round, small_model, staging, dp, chunk
+):
+    """Bit for bit, the round's params are its last program's weighted sum
+    divided by the round's weight, in the params' dtype."""
+    _, params0 = small_model
+    params, _, _, spy = fold_round(staging, dp, chunk)
+    ((wsum, total),) = spy.sums
+    assert total == np.float32(sum(FOLD_SIZES))
+    expected = jax.tree.map(lambda s, p: (s / total).astype(p.dtype), wsum, params0)
+    for got, want in zip(jax.tree.leaves(params), jax.tree.leaves(expected)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@fold_grid
+def test_round_dispatches_one_program_per_chunk(fold_round, staging, dp, chunk):
+    """A one-chunk round is one program call, and every round returns its
+    last program's own output leaves: no per-leaf op runs after it."""
+    params, _, stats, spy = fold_round(staging, dp, chunk)
+    chunks = math.ceil(len(FOLD_SIZES) / (chunk or len(FOLD_SIZES)))
+    assert len(spy.outputs) == stats["programs"] == stats["chunks"] == chunks
+    last_params, _ = spy.outputs[-1]
+    assert all(a is b for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(last_params)))
+
+
+@pytest.mark.parametrize(
+    "staging,dp,chunk",
+    [(s, d, c) for s in STAGINGS for d in DPS for c in CHUNKS if c is not None],
+)
+def test_chunked_round_matches_one_chunk(fold_round, staging, dp, chunk):
+    one_params, one_losses, _, _ = fold_round(staging, dp, None)
+    params, losses, _, _ = fold_round(staging, dp, chunk)
+    assert_params_close(one_params, params, atol=1e-6)
+    np.testing.assert_allclose(losses, one_losses, atol=1e-6, rtol=0)
+
+
+@pytest.mark.parametrize("staging", STAGINGS)
+@pytest.mark.parametrize("chunk", CHUNKS)
+def test_chunked_federation_builds_a_program_per_chunk_role(small_model, staging, chunk):
+    """First, middle and last chunk each have a program of their own; a
+    federation builds no more than its rounds' chunk roles need, and the
+    round span counts the programs a round dispatched."""
+    loss_fn, params0 = small_model
+    clients = [make_client(i, n, np.random.default_rng(i)) for i, n in enumerate(FOLD_SIZES)]
+    tracer = Tracer()
+    config = FederationConfig(
+        rounds=2, local_epochs=1, batch_size=8, seed=0,
+        cohort_chunk=chunk, staging=staging,
+    )
+    fed = Federation(config, clients, loss_fn, AdamW(learning_rate=5e-3), tracer=tracer)
+    fed.run(params0)
+    chunks = math.ceil(len(FOLD_SIZES) / (chunk or len(FOLD_SIZES)))
+    assert fed.cohort_trainer._round._cache_size() == min(chunks, 3)
+    spans = tracer.spans("round")
+    assert len(spans) == 2
+    assert all(span.args["programs"] == chunks for span in spans)

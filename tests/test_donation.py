@@ -69,58 +69,52 @@ def run_round(trainer, params, clients, seed=0):
     return new_params
 
 
-def test_donated_accumulator_is_invalidated(model):
-    """After the round step runs, the donated accumulator input is deleted
-    and any reuse raises — XLA really did alias it into the output."""
-    loss_fn, params = model
-    trainer = make_trainer(loss_fn, donate=True)
-    clients = make_clients(4, 8, np.random.default_rng(0))
+def staged_chunk(count: int, seed: int) -> tuple:
+    """One rebuild-staged chunk's device arguments to the round program."""
+    clients = make_clients(count, 8, np.random.default_rng(seed))
     sched = build_cohort_schedule([c.train for c in clients], 4, 1, np.random.default_rng(1))
     key_data = jnp.stack(
-        [jax.random.key_data(k) for k in jax.random.split(jax.random.key(0), 4)]
+        [jax.random.key_data(k) for k in jax.random.split(jax.random.key(0), count)]
     )
-    acc = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    acc_leaves = jax.tree.leaves(acc)
-    out_acc, _ = trainer._round(
-        params,
-        acc,
-        jnp.asarray(sched.x),
-        jnp.asarray(sched.y),
-        jnp.asarray(sched.mask),
-        jnp.asarray(sched.step_valid),
-        key_data,
-        jnp.asarray(sched.weights),
-    )
-    jax.block_until_ready(out_acc)
-    assert all(leaf.is_deleted() for leaf in acc_leaves)
-    with pytest.raises(REUSE_ERRORS, match="deleted"):
-        _ = acc_leaves[0] + 1.0
-    with pytest.raises(REUSE_ERRORS, match="deleted"):
-        np.asarray(acc_leaves[-1])
-    # the round's *output* accumulator is alive and well
-    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(out_acc))
+    arrays = (sched.x, sched.y, sched.mask, sched.step_valid)
+    return (*map(jnp.asarray, arrays), key_data, jnp.asarray(sched.weights))
+
+
+def test_donated_accumulator_is_invalidated(model):
+    """After a round program runs, the donated running-sum input is deleted
+    and any reuse raises — XLA really did alias it into the output: into the
+    next running sum, and at the round's last chunk into the averaged
+    params.  The params are never donated."""
+    loss_fn, params = model
+    trainer = make_trainer(loss_fn, donate=True)
+    chunks = [staged_chunk(4, seed) for seed in (0, 1, 2)]
+    total = np.float32(sum(float(chunk[-1].sum()) for chunk in chunks))
+    # a round's first program starts the sum, a middle one adds to it
+    first, _ = trainer._round(params, None, *chunks[0])
+    first_leaves = jax.tree.leaves(first)
+    middle, _ = trainer._round(params, first, *chunks[1])
+    middle_leaves = jax.tree.leaves(middle)
+    out, _ = trainer._round(params, middle, *chunks[2], total=total)
+    jax.block_until_ready(out)
+    for acc_leaves in (first_leaves, middle_leaves):
+        assert all(leaf.is_deleted() for leaf in acc_leaves)
+        with pytest.raises(REUSE_ERRORS, match="deleted"):
+            _ = acc_leaves[0] + 1.0
+        with pytest.raises(REUSE_ERRORS, match="deleted"):
+            np.asarray(acc_leaves[-1])
+    # the round's *output* params are alive and well, and so are its inputs
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(out))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(params))
 
 
 def test_undonated_buffers_survive(model):
     loss_fn, params = model
     trainer = make_trainer(loss_fn, donate=False)
-    clients = make_clients(3, 8, np.random.default_rng(2))
-    sched = build_cohort_schedule([c.train for c in clients], 4, 1, np.random.default_rng(1))
-    key_data = jnp.stack(
-        [jax.random.key_data(k) for k in jax.random.split(jax.random.key(0), 3)]
-    )
-    acc = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    out_acc, _ = trainer._round(
-        params,
-        acc,
-        jnp.asarray(sched.x),
-        jnp.asarray(sched.y),
-        jnp.asarray(sched.mask),
-        jnp.asarray(sched.step_valid),
-        key_data,
-        jnp.asarray(sched.weights),
-    )
-    jax.block_until_ready(out_acc)
+    chunks = [staged_chunk(3, seed) for seed in (2, 3)]
+    total = np.float32(sum(float(chunk[-1].sum()) for chunk in chunks))
+    acc, _ = trainer._round(params, None, *chunks[0])
+    out, _ = trainer._round(params, acc, *chunks[1], total=total)
+    jax.block_until_ready(out)
     assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(acc))
 
 

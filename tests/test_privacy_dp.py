@@ -221,7 +221,8 @@ def test_epsilon_after_matches_stepped_accountant():
 
 
 def _round_args(params, num_clients, steps=2, batch=4, seq=6, feat=38):
-    acc = jax.tree.map(jnp.zeros_like, params)
+    """A one-chunk round's program: no running sum in, the round's weight
+    in, the FedAvg mean out."""
     shape = (num_clients, steps)
     x = jnp.zeros(shape + (batch, seq, feat), jnp.float32)
     y = jnp.zeros(shape + (batch,), jnp.float32)
@@ -231,7 +232,7 @@ def _round_args(params, num_clients, steps=2, batch=4, seq=6, feat=38):
         [jax.random.key_data(jax.random.key(i)) for i in range(num_clients)]
     )
     w = jnp.ones((num_clients,), jnp.float32)
-    return (params, acc, x, y, m, valid, kd, w)
+    return (params, None, x, y, m, valid, kd, w, jnp.float32(num_clients))
 
 
 def _trainer(dp):

@@ -125,9 +125,10 @@ def test_ssd_backward_compiles_for_v5e(one_chip):
 
 def test_paper_round_with_pallas_compiles_for_v5e(one_chip, monkeypatch):
     """The resident round program (the one the engine runs every resident
-    round) at the all-clients round's packed shape, with ``use_pallas=True``
-    and the backend steered to TPU so the forward and backward kernels are
-    compiled, not interpreted."""
+    round: a one-chunk round's only program, which starts the FedAvg sum
+    and divides it) at the all-clients round's packed shape, with
+    ``use_pallas=True`` and the backend steered to TPU so the forward and
+    backward kernels are compiled, not interpreted."""
     from repro.federated.cohort import CohortTrainer
     from repro.models.gru import GRUConfig, init_gru, make_loss_fn
     from repro.optim.adamw import AdamW
@@ -155,8 +156,9 @@ def test_paper_round_with_pallas_compiles_for_v5e(one_chip, monkeypatch):
         + shapes(one_chip, (c,))
     )
     with cache_off():
+        total = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
         compiled = trainer._round.lower(
-            p, p, *args, feature_shape=(ROUND["hours"], ROUND["features"])
+            p, None, *args, total=total, feature_shape=(ROUND["hours"], ROUND["features"])
         ).compile()
     text = compiled.as_text()
     # forward and backward kernel of each of the 2 layers
